@@ -17,6 +17,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_reddit, bench_pagerank, bench_linear_algebra,
                    bench_tpch, bench_overhead, bench_drl_training,
                    bench_history, bench_kernels, bench_autopilot,

@@ -34,8 +34,9 @@ path paid.  Two executions of the same math, picked per backend
 
 * ``"fused"`` (TPU default) — everything inside one jit: the
   ``hash_partition_padded`` kernel emits pids with padding routed to an
-  overflow partition ``m``, ``scatter_perm`` computes the permutation with
-  an in-kernel prefix sum, and the packed gather/scatter rides the same
+  overflow partition ``m``, ``scatter_perm`` computes the permutation (a
+  stable rank on the MXU over prefix-summed base offsets), and the packed
+  gather/scatter rides the same
   trace.  One device dispatch per shuffle.
 * ``"hostperm"`` (CPU default) — XLA-on-CPU sorts/scatters are an order of
   magnitude slower than numpy, so the permutation is computed host-side
@@ -360,6 +361,12 @@ def plan_cache_stats() -> Dict[str, int]:
                 "calls": sum(p.calls for p in _PLANS.values())
                 + _RETIRED["calls"],
                 "evictions": _RETIRED["plans"]}
+
+
+def plan_keys() -> List[Tuple]:
+    """Keys of the live ShufflePlans, least recently used first."""
+    with _PLANS_LOCK:
+        return list(_PLANS)
 
 
 def reset_plan_cache_stats() -> None:
@@ -795,6 +802,16 @@ def _flat_slots(ds, v):
     return v.reshape((ds.num_workers * ds.capacity,) + v.shape[2:])
 
 
+def _one_device(v: jax.Array) -> jax.Array:
+    """A mesh-placed column gathered onto its lowest-id device.  XLA cannot
+    partition a Mosaic kernel, so a ShufflePlan fed a sharded input fails to
+    compile on TPU; the shuffle itself runs on one chip."""
+    if len(v.sharding.device_set) == 1:
+        return v
+    dev = min(v.sharding.device_set, key=lambda d: d.id)
+    return jax.device_put(v, jax.sharding.SingleDeviceSharding(dev))
+
+
 def flatten_dataset(ds, device_only: bool = False) -> Columns:
     """Flatten a StoredDataset's padded columns back to flat rows *without*
     a host round-trip: device-resident columns are gathered with a device
@@ -808,7 +825,8 @@ def flatten_dataset(ds, device_only: bool = False) -> Columns:
         if isinstance(v, jax.Array):
             if idx_dev is None:
                 idx_dev = jnp.asarray(idx.astype(np.int32))
-            out[k] = jnp.take(_flat_slots(ds, v), idx_dev, axis=0)
+            out[k] = jnp.take(_flat_slots(ds, _one_device(v)), idx_dev,
+                              axis=0)
         elif not device_only:
             out[k] = _flat_slots(ds, np.asarray(v))[idx]
     return out

@@ -16,14 +16,19 @@ Three kernels (DESIGN §5):
   past the valid region.  This is what lets one jitted dispatch plan serve
   every N in a shape bucket without retracing.
 * :func:`scatter_perm` — the counting-sort scatter stage: consume
-  ``(pids, counts)``, compute per-partition offsets with an in-kernel
-  exclusive prefix sum, and emit the destination permutation directly —
-  an O(N) *stable* placement replacing the O(N log N) ``argsort`` the
+  ``(pids, counts)`` and emit the destination permutation directly — an
+  O(N) *stable* placement replacing the O(N log N) ``argsort`` the
   re-bucket used to pay.
 
 Tiling: grid over key blocks; each step processes a (block,) tile in VMEM
 and carries per-partition state ((m,) histogram / running offsets) in VMEM
 scratch across steps (the grid dim is sequential on TPU).
+
+Mosaic constraints the kernels are written around: a boolean vector cannot
+be reshaped to a column (masks are applied to int32 pids, never to a
+``[:, None]`` bool), and ``cumsum`` has no TPU lowering — the base offsets
+are an exclusive prefix sum taken in the wrapper, and the within-block
+stable rank is a strictly-lower-triangular matmul on the MXU.
 """
 
 from __future__ import annotations
@@ -35,10 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax 0.4.x names this TPUCompilerParams; newer releases renamed it
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
 
 DEFAULT_BLOCK = 2048
 
@@ -67,12 +68,11 @@ def _kernel(keys_ref, pids_ref, counts_ref, hist_ref, *,
     pid = (x % jnp.uint32(num_partitions)).astype(jnp.int32)
     pids_ref[...] = pid
 
-    # mask padding tail so it never lands in the histogram
+    # padding tail → sentinel m, which matches no histogram column
     pos = i * block + jax.lax.broadcasted_iota(jnp.int32, (block,), 0)
-    valid = pos < n_valid
+    pid = jnp.where(pos < n_valid, pid, num_partitions)
     onehot = (pid[:, None] == jax.lax.broadcasted_iota(
         jnp.int32, (block, num_partitions), 1))
-    onehot = jnp.where(valid[:, None], onehot, False)
     hist_ref[...] += onehot.astype(jnp.int32).sum(axis=0)
 
     @pl.when(i == nb - 1)
@@ -102,7 +102,7 @@ def hash_partition(keys: jax.Array, num_partitions: int, *,
         out_shape=[jax.ShapeDtypeStruct((keys.shape[0],), jnp.int32),
                    jax.ShapeDtypeStruct((num_partitions,), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((num_partitions,), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(keys)
@@ -172,7 +172,7 @@ def hash_partition_padded(keys: jax.Array, n_valid: jax.Array,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B,), jnp.int32),
                    jax.ShapeDtypeStruct((m1,), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(jnp.asarray(n_valid, jnp.int32).reshape(1), keys)
@@ -183,26 +183,36 @@ def hash_partition_padded(keys: jax.Array, n_valid: jax.Array,
 # Counting-sort scatter: (pids, counts) → destination permutation, O(N)
 # ---------------------------------------------------------------------------
 
-def _perm_kernel(pids_ref, counts_ref, dest_ref, offs_ref, *,
-                 num_partitions: int, block: int):
+RANK_TILE = 128      # rows per MXU rank tile (one 128x128 MXU pass)
+
+
+def _perm_kernel(pids_ref, offs_ref, dest_ref, run_ref, *, block: int,
+                 tile: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
-        # in-kernel exclusive prefix sum of the histogram → base offsets
-        c = counts_ref[...]
-        offs_ref[...] = jnp.cumsum(c) - c
+        run_ref[...] = offs_ref[...]
 
-    pid = pids_ref[...]                                    # (block,) int32
-    onehot = (pid[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (block, num_partitions), 1))
-    oh = onehot.astype(jnp.int32)
-    # stable within-block rank of each row among same-pid rows
-    rank = ((jnp.cumsum(oh, axis=0) - oh) * oh).sum(axis=1)
-    base = (offs_ref[...][None, :] * oh).sum(axis=1)
-    dest_ref[...] = base + rank
-    # carry: partitions already filled by this block
-    offs_ref[...] += oh.sum(axis=0)
+    lanes = run_ref.shape[1]
+    cols = jax.lax.broadcasted_iota(jnp.int32, (tile, lanes), 1)
+    # strictly-lower-triangular ones: (tri @ onehot)[r, p] counts the rows
+    # above r in the tile with pid p.  0/1 entries are exact in bf16 and the
+    # f32 accumulation is exact up to 2^24, far above the tile height.
+    tri = (jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 0)
+           > jax.lax.broadcasted_iota(jnp.int32, (tile, tile), 1)
+           ).astype(jnp.float32).astype(jnp.bfloat16)
+    run = run_ref[...]             # (1, lanes): next free slot per partition
+    for t in range(block // tile):
+        pid = pids_ref[pl.ds(t * tile, tile)]                   # (tile,)
+        onehot = pid[:, None] == cols
+        oh = onehot.astype(jnp.int32)
+        above = jnp.dot(tri, oh.astype(jnp.float32).astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+        dest_ref[pl.ds(t * tile, tile)] = jnp.where(
+            onehot, above.astype(jnp.int32) + run, 0).sum(axis=1)
+        run = run + oh.sum(axis=0, keepdims=True)
+    run_ref[...] = run
 
 
 def scatter_perm(pids: jax.Array, counts: jax.Array, *,
@@ -211,32 +221,45 @@ def scatter_perm(pids: jax.Array, counts: jax.Array, *,
     """(pids (N,) int32, counts (m,) int32) → dest (N,) int32.
 
     ``dest[i]`` = position of row i in the stable sort of ``pids`` — the
-    counting-sort placement (base offset from the in-kernel prefix sum +
-    running per-partition fill + within-block stable rank).  O(N·m/VPU)
-    with no sort; sentinel pids outside [0, m) get garbage dests without
-    perturbing any real row's slot (their one-hot row is all-False).
+    counting-sort placement (base offset from the exclusive prefix sum of
+    ``counts`` + running per-partition fill + within-tile stable rank).
+    O(N·m) one-hot work with no sort; sentinel pids outside [0, m) get dest
+    0 without perturbing any real row's slot (their one-hot row is empty).
+    Blocks above :data:`RANK_TILE` rows round up to a multiple of it.
     """
     n = pids.shape[0]
     if n == 0:
         return jnp.zeros(0, jnp.int32)
     m = counts.shape[0]
     block = min(block, max(8, n))
+    if block > RANK_TILE:
+        block = -(-block // RANK_TILE) * RANK_TILE
+    tile = min(block, RANK_TILE)
+    pids = pids.astype(jnp.int32)
+    # out-of-range pids (sentinels, padding) → -1: no lane ever matches it
+    pids = jnp.where((pids >= 0) & (pids < m), pids, -1)
     pad = (-n) % block
-    if pad:                       # sentinel never matches a real partition
+    if pad:
         pids = jnp.pad(pids, (0, pad), constant_values=-1)
     nb = pids.shape[0] // block
+    # exclusive prefix sum of the histogram → per-partition base offsets,
+    # lane-padded to a multiple of 128
+    lanes = -(-m // 128) * 128
+    counts = counts.astype(jnp.int32)
+    offs = jnp.zeros((1, lanes), jnp.int32).at[0, :m].set(
+        jnp.cumsum(counts) - counts)
 
-    kernel = functools.partial(_perm_kernel, num_partitions=m, block=block)
+    kernel = functools.partial(_perm_kernel, block=block, tile=tile)
     dest = pl.pallas_call(
         kernel,
         grid=(nb,),
         in_specs=[pl.BlockSpec((block,), lambda i: (i,)),
-                  pl.BlockSpec((m,), lambda i: (0,))],
+                  pl.BlockSpec((1, lanes), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((block,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((pids.shape[0],), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((m,), jnp.int32)],
-        compiler_params=_CompilerParams(
+        scratch_shapes=[pltpu.VMEM((1, lanes), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(pids.astype(jnp.int32), counts.astype(jnp.int32))
+    )(pids, offs)
     return dest[:n]

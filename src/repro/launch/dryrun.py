@@ -31,21 +31,6 @@ from .mesh import (HBM_BW, ICI_LINK_BW, PEAK_FLOPS_BF16, make_production_mesh,
                    mesh_counts)
 
 
-def _mesh_context(mesh):
-    """jax >= 0.5 exposes jax.set_mesh; on 0.4.x the Mesh object itself is
-    the context manager that installs the global mesh."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    return set_mesh(mesh) if set_mesh is not None else mesh
-
-
-def _cost_dict(compiled):
-    """compiled.cost_analysis() returns a dict (>=0.5) or [dict] (0.4.x)."""
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _named(mesh, spec_tree):
     return jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), spec_tree,
@@ -72,7 +57,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     shape = SHAPES[shape_name]
     enable_spmd(True)
 
-    with _mesh_context(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = steps.make_optimizer(cfg)
             inp = specs.input_specs(cfg, shape, opt)
@@ -132,7 +117,7 @@ def analyze_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
     chips = mesh.devices.size
 
     mem = compiled.memory_analysis()
-    cost = _cost_dict(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     totals = hlo_analysis.analyze(hlo)      # loop-aware (scan bodies × trips)
     colls = totals.collectives
@@ -238,7 +223,7 @@ def main():
     compiled, lowered, _ = lower_cell(args.arch, args.shape,
                                       multi_pod=args.multi_pod)
     print(compiled.memory_analysis())
-    print({k: v for k, v in _cost_dict(compiled).items()
+    print({k: v for k, v in (compiled.cost_analysis() or {}).items()
            if k in ("flops", "bytes accessed")})
     print(json.dumps(rec, indent=1, default=str))
 

@@ -15,10 +15,7 @@ from repro.core.sharding_bridge import specs_match, would_elide_collective
 def _mesh(multi_pod=False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     names = ("pod", "data", "model") if multi_pod else ("data", "model")
-    try:                                  # jax >= 0.5 signature
-        return AbstractMesh(shape, names)
-    except TypeError:                     # jax 0.4.x: tuple of (name, size)
-        return AbstractMesh(tuple(zip(names, shape)))
+    return AbstractMesh(shape, names)
 
 
 @pytest.mark.parametrize("arch", list_archs())
@@ -113,8 +110,6 @@ def test_hlo_analyzer_counts_scan_trips():
     assert abs(t.flops - expect) / expect < 0.05
     # XLA's own cost analysis counts the body once — our analyzer must not
     ca = c.cost_analysis()
-    if isinstance(ca, list):              # jax 0.4.x returns [dict]
-        ca = ca[0]
     assert t.flops > (ca.get("flops", 0) or 0) * (G - 1)
 
 

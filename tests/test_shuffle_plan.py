@@ -341,6 +341,45 @@ def test_d2d_repartition_stays_mesh_placed():
     assert dev.read(new.name) is new        # placement persisted in the store
 
 
+_MESH_PHASE = """
+import sys
+sys.path[:0] = [{root!r}, {src!r}]
+from repro.data import device_repartition as dr
+dr.default_mode = lambda: "fused"
+import chip_smoke as cs
+tables = cs.make_tables(0.002, 3)
+cs.phase_mesh(tables, {{"q17": cs.ref_q17(tables)}})
+ds = cs.lachesis.Session(num_workers=8, backend="device")
+ds.write("lineitem", tables["lineitem"])
+ds.repartition("lineitem", cs.candidate(cs.q_lineitem_part(), "lineitem"),
+               mesh=cs.Mesh(cs.np.array(cs.jax.devices()), ("data",)))
+col = ds.read("lineitem").columns["qty"]
+assert len(col.sharding.device_set) == 4
+assert len(dr._one_device(col).sharding.device_set) == 1
+print("MESH-OK")
+"""
+
+
+def test_chip_smoke_mesh_phase_on_four_cpu_devices():
+    """chip_smoke's --chips 4 phase on four host devices with the fused
+    plan: a mesh-placed dataset is repartitioned again (gathered onto one
+    device first) and the q17-like result equals the one-device run."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         _MESH_PHASE.format(root=root, src=os.path.join(root, "src"))],
+        capture_output=True, text=True, env=env, cwd=root, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
+    assert "mesh: q17-like on the mesh-placed layout equals the one-chip" \
+        in out.stdout
+    assert out.stdout.rstrip().endswith("MESH-OK")
+
+
 def test_flatten_dataset_matches_gather():
     wl, tables = _reddit_like(n_sub=777, n_auth=99, seed=5)
     dev = PartitionStore(6, backend="device")
