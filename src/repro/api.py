@@ -273,8 +273,7 @@ class Session:
                 self._resolve_backend(backend),
                 history=history, hooks=tuple(self.run_hooks),
                 timestamp=timestamp)
-            sp.set(cache_hit=stats.plan_cache_hit,
-                   wall_ms=round(stats.wall_s * 1e3, 3))
+            sp.set(cache_hit=stats.plan_cache_hit)
         if getattr(self.store, "telemetry", None) is not None:
             self._record_run_profile(wl, stats, plan)
         if workload is None and wl is self._current:
@@ -510,7 +509,6 @@ class _ProcessCollectors:
                 yield f"shuffleplan_cache_{k}", {}, v
         st = _obs_tracer.TRACER.stats()
         yield "tracer_spans_buffered", {}, st["buffered"]
-        yield "tracer_spans_dropped_total", {}, st["dropped"]
         # canonical names (DESIGN §15): ring-buffer loss + current mode,
         # so silent span drops and "why is my trace empty" (mode=off)
         # are both answerable from session.metrics() alone

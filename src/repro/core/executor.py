@@ -86,7 +86,6 @@ class EngineStats:
     shuffle_bytes: int = 0
     device_repartitions: int = 0     # shuffles routed through the Pallas path
     match_overhead_s: float = 0.0    # plan-time Alg. 4 cost (0 on cache hits)
-    stage_latency: Dict[str, float] = field(default_factory=dict)
     wall_s: float = 0.0
     shuffle_s: float = 0.0           # wall time spent inside real shuffles
     input_bytes: int = 0             # bytes scanned from the store
@@ -149,8 +148,7 @@ class Executor:
                 plan, history=history, hooks=hooks, timestamp=timestamp,
                 workload=workload, planning_s=planning_s,
                 cache_hit=cache_hit)
-            rsp.set(wall_ms=round(stats.wall_s * 1e3, 3),
-                    shuffles=stats.shuffles_performed,
+            rsp.set(shuffles=stats.shuffles_performed,
                     elided=stats.shuffles_elided)
             return vals, stats
 
@@ -199,7 +197,6 @@ class Executor:
 
         for step in plan.steps:
             node = g.nodes[step.nid]
-            t0 = time.perf_counter()
             kind = step.kind
             parents = g.parents(step.nid)
 
@@ -211,9 +208,13 @@ class Executor:
                     # planned for), held as an object — immune to
                     # concurrent pointer flips
                     ds = scans[step.nid]
-                    flat = ds.gather()
-                    dev = device_flat_columns(ds) if step.device_relay \
-                        else None
+                    with _span("scan.fetch", "exec", d2h_bytes=0):
+                        flat = ds.gather()
+                    dev = None
+                    if step.device_relay:
+                        with _span("scan.relay", "exec", h2d_bytes=0) as rsp:
+                            dev = device_flat_columns(ds)
+                            rsp.set(columns=len(dev or ()))
                     stats.input_bytes += ds.nbytes
                     stats.padded_bytes += int(ds.padded_bytes)
                     stats.valid_bytes += int(ds.valid_bytes)
@@ -260,9 +261,6 @@ class Executor:
                     args = [vals[p].columns if isinstance(vals[p], TableVal)
                             else vals[p] for p in parents]
                     vals[step.nid] = fn(*args)
-            stats.stage_latency[f"{step.nid}:{node.label}"] = \
-                stats.stage_latency.get(f"{step.nid}:{node.label}", 0.0) + \
-                (time.perf_counter() - t0)
 
         stats.wall_s = time.perf_counter() - t_start
         if io0:

@@ -70,6 +70,7 @@ from ..kernels.hash_partition.ops import (padded_partition_ids,
                                           partition_ids, scatter_permutation)
 from ..obs.tracer import span as _span
 from .capacity import CapacityMap, bucket_capacity, valid_slot_index
+from .transfer import fetch, fetch_tree, upload
 
 Columns = Dict[str, Any]
 
@@ -131,12 +132,12 @@ def as_kernel_keys(keys) -> jax.Array:
         return k.astype(jnp.int32)
     k = np.asarray(keys).reshape(-1)
     if np.issubdtype(k.dtype, np.integer):
-        return jnp.asarray(k.astype(np.int32))
+        return upload(k.astype(np.int32))
     if k.dtype == np.float64:                     # jnp canonicalizes f64→f32
         k = k.astype(np.float32)
     if k.dtype == np.float32:
-        return jnp.asarray(k.view(np.int32))
-    return jnp.asarray(k.astype(np.int32))
+        return upload(k.view(np.int32))
+    return upload(k.astype(np.int32))
 
 
 def _host_kernel_keys(keys) -> np.ndarray:
@@ -201,14 +202,14 @@ def shuffle_pids(keys, num_partitions: int, *,
         pids, hist = device_partition_ids(keys, num_partitions,
                                           interpret=interpret,
                                           use_kernel=use_kernel)
-        return pids, np.asarray(hist).astype(np.int64)
+        return pids, fetch(hist).astype(np.int64)
     if isinstance(keys, jax.Array):
         # bucket the key length so the elementwise jit never retraces per N
         k = as_kernel_keys(keys)
         n = int(k.shape[0])
         B = shape_bucket(n)
         k_p = k if n == B else jnp.zeros(B, jnp.int32).at[:n].set(k)
-        pids = np.asarray(_hash_pids_jit(k_p, num_partitions))[:n]
+        pids = fetch(_hash_pids_jit(k_p, num_partitions))[:n]
     else:
         pids = (_host_wang(_host_kernel_keys(keys))
                 % np.uint32(num_partitions)).astype(np.int32)
@@ -303,7 +304,7 @@ def _build_packs(dev_cols: List[Tuple[str, Any]], n: int,
         on_device = any(isinstance(by_name[nm], jax.Array)
                         for nm, *_ in p.members)
         if on_device:         # keep the pack on device — no host round-trip
-            flat = [jnp.asarray(by_name[nm]).reshape(n, -1)
+            flat = [upload(by_name[nm]).reshape(n, -1)
                     for nm, *_ in p.members]
             cat = flat[0] if len(flat) == 1 else jnp.concatenate(flat, axis=1)
             p.data = jnp.zeros((rows, p.width), p.dtype).at[:n].set(cat)
@@ -435,7 +436,7 @@ def _fused_rebucket_plan(m: int, B: int, spec: Tuple, interpret: bool,
     key = ("rebucket", m, B, spec, interpret, use_kernel, "fused")
 
     def build(plan: ShufflePlan):
-        def fn(keys, n, packs):
+        def shuffle_rebucket(keys, n, packs):
             plan.traces += 1
             pids, counts_full = padded_partition_ids(
                 keys, n, m, interpret=interpret, use_kernel=use_kernel)
@@ -447,7 +448,7 @@ def _fused_rebucket_plan(m: int, B: int, spec: Tuple, interpret: bool,
                 jnp.arange(B, dtype=jnp.int32))
             outs = tuple(jnp.take(p, order, axis=0) for p in packs)
             return order, counts_full[:m], outs
-        return fn
+        return shuffle_rebucket
 
     return _get_plan(key, build)
 
@@ -458,10 +459,10 @@ def _hostperm_rebucket_plan(m: int, B: int, spec: Tuple) -> ShufflePlan:
     key = ("rebucket", m, B, spec, "hostperm")
 
     def build(plan: ShufflePlan):
-        def fn(order, packs):
+        def shuffle_rebucket_hostperm(order, packs):
             plan.traces += 1
             return tuple(jnp.take(p, order, axis=0) for p in packs)
-        return fn
+        return shuffle_rebucket_hostperm
 
     return _get_plan(key, build)
 
@@ -479,7 +480,7 @@ def _fused_scatter_plan(m: int, B: int, R: int, spec: Tuple,
     key = ("scatter", m, B, R, spec, interpret, use_kernel, "fused")
 
     def build(plan: ShufflePlan):
-        def fn(pids, counts, n, slot_offs, packs):
+        def store_scatter(pids, counts, n, slot_offs, packs):
             plan.traces += 1
             counts_full = jnp.concatenate(
                 [counts.astype(jnp.int32),
@@ -498,7 +499,7 @@ def _fused_scatter_plan(m: int, B: int, R: int, spec: Tuple,
                 .at[flat_dest].set(p)[:R]
                 for p in packs)
             return flat_dest, outs
-        return fn
+        return store_scatter
 
     return _get_plan(key, build)
 
@@ -513,10 +514,10 @@ def _hostperm_scatter_plan(m: int, B: int, R: int,
     key = ("scatter", m, B, R, spec, "hostperm")
 
     def build(plan: ShufflePlan):
-        def fn(inv, packs):
+        def store_scatter_hostperm(inv, packs):
             plan.traces += 1
             return tuple(jnp.take(p, inv, axis=0) for p in packs)
-        return fn
+        return store_scatter_hostperm
 
     return _get_plan(key, build)
 
@@ -575,18 +576,19 @@ def device_rebucket_full(columns: Columns, key_vals, num_partitions: int, *,
     spec = _pack_spec(packs)
 
     with _span("shuffle.dispatch", "shuffle", op="rebucket", rows=n, m=m,
-               bucket=B, mode=mode):
+               bucket=B, mode=mode, h2d_bytes=0, d2h_bytes=0) as sp:
         if mode == "fused":
             keys_p = jnp.zeros(B, jnp.int32).at[:n].set(
                 as_kernel_keys(key_arr))
             plan = _fused_rebucket_plan(m, B, spec, interpret, use_kernel)
             plan.calls += 1
             order_d, counts_d, outs_d = plan.fn(
-                keys_p, jnp.int32(n),
-                tuple(jnp.asarray(p.data) for p in packs))
-            # one transfer for everything the host needs
-            order_np, counts_np, outs_np = jax.device_get(
-                (order_d, counts_d, outs_d))
+                keys_p, jnp.int32(n), tuple(upload(p.data) for p in packs))
+            # one transfer for everything the host needs; its bytes count
+            # on the dispatch
+            with _span("shuffle.fetch", "shuffle"):
+                order_np, counts_np, outs_np = fetch_tree(
+                    (order_d, counts_d, outs_d), sp)
             order_valid = order_np[:n]
             counts_np = counts_np.astype(np.int64)
         else:
@@ -596,9 +598,10 @@ def device_rebucket_full(columns: Columns, key_vals, num_partitions: int, *,
                 [order_valid, np.arange(n, B)]).astype(np.int32)
             plan = _hostperm_rebucket_plan(m, B, spec)
             plan.calls += 1
-            outs_d = plan.fn(jnp.asarray(order_p),
-                             tuple(jnp.asarray(p.data) for p in packs))
-            outs_np = jax.device_get(outs_d)
+            outs_d = plan.fn(upload(order_p),
+                             tuple(upload(p.data) for p in packs))
+            with _span("shuffle.fetch", "shuffle"):
+                outs_np = fetch_tree(outs_d, sp)
 
     out: Columns = {}
     device_out: Columns = {}
@@ -720,7 +723,7 @@ def device_scatter_padded(flat_columns: Columns, pids, counts, *,
     R = shape_bucket(total)  # output-row bucket: offsets traced, not keyed
 
     with _span("shuffle.dispatch", "shuffle", op="scatter", rows=n, m=m,
-               bucket=B, mode=mode):
+               bucket=B, mode=mode, h2d_bytes=0, d2h_bytes=0):
         if mode == "fused":
             packs = _build_packs(dev_cols, n, B)
             if isinstance(pids, jax.Array):
@@ -729,30 +732,29 @@ def device_scatter_padded(flat_columns: Columns, pids, counts, *,
             else:
                 buf = np.full(B, m, np.int32)
                 buf[:n] = np.asarray(pids).astype(np.int32)
-                pids_p = jnp.asarray(buf)
+                pids_p = upload(buf)
             plan = _fused_scatter_plan(m, B, R, _pack_spec(packs), interpret,
                                        use_kernel)
             plan.calls += 1
             flat_dest_d, outs = plan.fn(
-                pids_p, jnp.asarray(counts_np.astype(np.int32)),
-                jnp.int32(n), jnp.asarray(offsets_np.astype(np.int32)),
-                tuple(jnp.asarray(p.data) for p in packs))
+                pids_p, upload(counts_np.astype(np.int32)), jnp.int32(n),
+                upload(offsets_np.astype(np.int32)),
+                tuple(upload(p.data) for p in packs))
             flat_dest_np = None
             if host_cols:
-                flat_dest_np = np.asarray(flat_dest_d)[:n]
+                flat_dest_np = fetch(flat_dest_d)[:n]
         else:
             # rows [n:B] of each pack are zeros; row B is the explicit trash
             # source every empty (worker, slot) cell gathers from
             packs = _build_packs(dev_cols, n, B + 1)
-            pids_np = np.asarray(pids).astype(np.int64)
+            pids_np = fetch(pids).astype(np.int64)
             flat_dest_np = host_counting_sort_dest(pids_np, counts_np, cap,
                                                    dest_offsets=offsets_np)
             inv = np.full(R, B, np.int32)
             inv[flat_dest_np] = np.arange(n, dtype=np.int32)
             plan = _hostperm_scatter_plan(m, B, R, _pack_spec(packs))
             plan.calls += 1
-            outs = plan.fn(jnp.asarray(inv),
-                           tuple(jnp.asarray(p.data) for p in packs))
+            outs = plan.fn(upload(inv), tuple(upload(p.data) for p in packs))
 
     columns: Columns = {}
     for p, mat in zip(packs, outs):
@@ -824,7 +826,7 @@ def flatten_dataset(ds, device_only: bool = False) -> Columns:
     for k, v in ds.columns.items():
         if isinstance(v, jax.Array):
             if idx_dev is None:
-                idx_dev = jnp.asarray(idx.astype(np.int32))
+                idx_dev = upload(idx.astype(np.int32))
             out[k] = jnp.take(_flat_slots(ds, _one_device(v)), idx_dev,
                               axis=0)
         elif not device_only:
@@ -858,10 +860,12 @@ def device_repartition_dataset(ds, partitioner, num_partitions: int, *,
     choose a bucketed layout from the fresh histogram; returns the map it
     used (None ⇒ uniform ``(m, capacity, ...)``).
     """
-    flat = flatten_dataset(ds)
-    keys = partitioner.key_fn()(flat)
-    pids, counts = shuffle_pids(keys, num_partitions, interpret=interpret,
-                                use_kernel=use_kernel, mode=mode)
+    with _span("store.flatten", "store", h2d_bytes=0):
+        flat = flatten_dataset(ds)
+    with _span("store.pids", "store", h2d_bytes=0, d2h_bytes=0):
+        keys = partitioner.key_fn()(flat)
+        pids, counts = shuffle_pids(keys, num_partitions, interpret=interpret,
+                                    use_kernel=use_kernel, mode=mode)
     cmap = plan_capacity(counts) if plan_capacity is not None else None
     columns = device_scatter_padded(flat, pids, counts, capacity_map=cmap,
                                     interpret=interpret,

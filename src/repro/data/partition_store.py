@@ -48,6 +48,7 @@ from .device_repartition import (device_repartition_dataset,
                                  device_scatter_padded, dtype_roundtrips,
                                  flatten_dataset, host_counting_sort_dest,
                                  shuffle_pids)
+from .transfer import fetch
 
 
 Columns = Dict[str, np.ndarray]
@@ -117,6 +118,10 @@ class StoredDataset:
     created_at: float = field(default_factory=time.time)
     generation: int = 0
     capacity_map: Optional[CapacityMap] = None
+    # name → (device column, its host copy): each device column crosses to
+    # the host once per generation, however often it is scanned
+    _host: Dict[str, Tuple[Any, np.ndarray]] = field(
+        default_factory=dict, init=False, repr=False, compare=False)
 
     @property
     def num_workers(self) -> int:
@@ -193,20 +198,39 @@ class StoredDataset:
         worker-major rows through its slot-offset index, so the output is
         bit-identical across layouts."""
         counts = np.asarray(self.counts)
+        cols = self.host_columns()
         if self.capacity_map is not None:
             idx = valid_slot_index(counts, self.capacity_map.offsets)
-            return {k: np.asarray(v)[idx] for k, v in self.columns.items()}
+            return {k: v[idx] for k, v in cols.items()}
         m, cap = self.num_workers, self.capacity
         mask = (np.arange(cap) < counts[:, None]).reshape(-1)
+        return {k: v.reshape((m * cap,) + v.shape[2:])[mask]
+                for k, v in cols.items()}
+
+    def host_columns(self) -> Columns:
+        """Every column as a host array, layout unchanged.  A device column
+        is fetched on first use and its copy kept with it (a swapped
+        column container is fetched anew)."""
         out: Columns = {}
         for k, v in self.columns.items():
-            v = np.asarray(v)
-            out[k] = v.reshape((m * cap,) + v.shape[2:])[mask]
+            if isinstance(v, jax.Array):
+                src, host = self._host.get(k, (None, None))
+                if src is not v:
+                    host = fetch(v)
+                    self._host[k] = (v, host)
+                v = host
+            out[k] = np.asarray(v)
         return out
+
+    def set_column(self, name: str, v) -> None:
+        """Swap column ``name``'s container (same values), dropping the
+        host copy of the container it replaces."""
+        self.columns[name] = v
+        self._host.pop(name, None)
 
     def to_host(self) -> "StoredDataset":
         """Copy with every column materialized as numpy (layout unchanged)."""
-        cols = {k: np.asarray(v) for k, v in self.columns.items()}
+        cols = self.host_columns()
         return StoredDataset(name=self.name, columns=cols,
                              counts=self.counts, partitioner=self.partitioner,
                              num_rows=self.num_rows, nbytes=self.nbytes,
@@ -641,7 +665,7 @@ class PartitionStore:
         for k in list(ds.columns):
             self._sync("spill:column")
             with self._swap_lock:
-                ds.columns[k] = cols[k]
+                ds.set_column(k, cols[k])
         self._sync("spill:post_swap")
         self.durable.io_add(spills=1, spilled_bytes=freed)
         return True
@@ -687,7 +711,7 @@ class PartitionStore:
                 self._sync("prefetch:pre_swap")
                 with self._swap_lock:
                     for k in list(ds.columns):
-                        ds.columns[k] = promoted[k]
+                        ds.set_column(k, promoted[k])
                 if self.durable is not None:
                     self.durable.io_add(bytes_read=loaded,
                                         read_s=time.perf_counter() - t0,
@@ -819,14 +843,15 @@ class PartitionStore:
         dispatch (SaltedPartitioner's pid math is not the plain key hash) —
         keep their host pid computation but still scatter on device, so the
         stored columns are device-resident."""
-        if (partitioner.strategy == HASH and partitioner.graph is not None
-                and getattr(partitioner, "kernel_dispatchable", True)):
-            keys = partitioner.key_fn()(data)
-            pids, counts = shuffle_pids(keys, self.m,
-                                        interpret=self.interpret)
-        else:
-            pids = self._host_pids(data, partitioner, n, seed)
-            counts = np.bincount(pids, minlength=self.m).astype(np.int64)
+        with _span("store.pids", "store", h2d_bytes=0, d2h_bytes=0):
+            if (partitioner.strategy == HASH and partitioner.graph is not None
+                    and getattr(partitioner, "kernel_dispatchable", True)):
+                keys = partitioner.key_fn()(data)
+                pids, counts = shuffle_pids(keys, self.m,
+                                            interpret=self.interpret)
+            else:
+                pids = self._host_pids(data, partitioner, n, seed)
+                counts = np.bincount(pids, minlength=self.m).astype(np.int64)
         cmap = self._plan_cmap(counts)
         columns = device_scatter_padded(data, pids, counts,
                                         capacity_map=cmap,

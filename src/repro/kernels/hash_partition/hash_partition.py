@@ -105,6 +105,7 @@ def hash_partition(keys: jax.Array, num_partitions: int, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="hash_partition",
     )(keys)
     return pids[:n], counts
 
@@ -175,6 +176,7 @@ def hash_partition_padded(keys: jax.Array, n_valid: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="hash_partition_padded",
     )(jnp.asarray(n_valid, jnp.int32).reshape(1), keys)
     return pids, counts
 
@@ -261,5 +263,6 @@ def scatter_perm(pids: jax.Array, counts: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="scatter_perm",
     )(pids, offs)
     return dest[:n]

@@ -8,6 +8,13 @@ thread-local context stack and stamped off one monotonic clock
 export as Chrome ``trace_event`` JSON (:mod:`repro.obs.export`) loadable
 in Perfetto / ``chrome://tracing``.
 
+While a recording mode is on, every recorded span is also mirrored into
+``jax.profiler`` as a ``TraceAnnotation`` of the same name: a profile
+taken with ``jax.profiler.start_trace`` then holds the program's spans on
+its host plane, on the profiler's own clock, next to the device's
+operations.  JAX is imported at the first ``configure`` to a recording
+mode; without JAX there is no mirror.
+
 Overhead contract: tracing is **off by default** and the disabled path is
 one module-global load plus one shared no-op object — no allocation, no
 clock read, no lock (``bench_overhead.tracing_overhead`` prices it
@@ -41,17 +48,18 @@ clock and draws the handoff as a cross-process flow arrow.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import json
 import os
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Mapping, Optional
+from typing import Any, ClassVar, Dict, List, Mapping, Optional
 
 __all__ = ["Span", "TraceContext", "Tracer", "TRACER", "TRACE_ENV_VAR",
-           "span", "configure", "enable", "disable", "tracing_mode",
-           "finished_spans", "open_spans", "clear_spans"]
+           "span", "current_span", "configure", "enable", "disable",
+           "tracing_mode", "finished_spans", "open_spans", "clear_spans"]
 
 #: env-var carrier for a wire-format TraceContext (spawned subprocesses)
 TRACE_ENV_VAR = "LACHESIS_TRACE_CONTEXT"
@@ -80,6 +88,12 @@ class Span:
     # (parent span id, parent tid, capture time) — the exporter emits a
     # Chrome flow arrow from there to this span's start
     flow_from: Optional["TraceContext"] = None
+    # the open jax.profiler annotation mirroring this span (None when
+    # there is no mirror)
+    _mirror: Any = field(default=None, repr=False, compare=False)
+
+    #: True on a span that records: a site computes costly args only then
+    recording: ClassVar[bool] = True
 
     @property
     def dur_s(self) -> Optional[float]:
@@ -88,6 +102,11 @@ class Span:
     def set(self, **kw) -> "Span":
         """Attach key=value annotations (shown in the trace viewer)."""
         self.args.update(kw)
+        return self
+
+    def add(self, key: str, n: int) -> "Span":
+        """Add ``n`` to the numeric annotation ``key`` (0 when absent)."""
+        self.args[key] = self.args.get(key, 0) + n
         return self
 
     # -- context manager -----------------------------------------------------
@@ -105,6 +124,7 @@ class _NullSpan:
     """The shared disabled span: every operation is a no-op returning
     ``self`` so instrumentation sites never branch on the mode."""
     __slots__ = ()
+    recording = False
 
     def __enter__(self) -> "_NullSpan":
         return self
@@ -115,6 +135,9 @@ class _NullSpan:
     def set(self, **kw) -> "_NullSpan":
         return self
 
+    def add(self, key: str, n: int) -> "_NullSpan":
+        return self
+
 
 NULL_SPAN = _NullSpan()
 
@@ -123,6 +146,7 @@ class _SuppressSpan:
     """Root-not-sampled marker: suppresses child recording for its extent
     (so a sampled tracer emits whole trees or nothing)."""
     __slots__ = ("_local",)
+    recording = False
 
     def __init__(self, local):
         self._local = local
@@ -136,6 +160,9 @@ class _SuppressSpan:
         return False
 
     def set(self, **kw) -> "_SuppressSpan":
+        return self
+
+    def add(self, key: str, n: int) -> "_SuppressSpan":
         return self
 
 
@@ -217,12 +244,14 @@ class Tracer:
         self.sample_every = 16
         self.process = f"pid-{os.getpid()}"    # label for cross-process merge
         self._buffer = int(buffer)
-        self._spans: List[Span] = []
+        self._spans: "collections.deque[Span]" = collections.deque(
+            maxlen=self._buffer)
         self._open: Dict[int, Span] = {}       # span_id → in-flight span
         self._lock = threading.Lock()          # guards ring buffer + _open
         self._local = _Local()
         self._sample_clock = itertools.count()
         self.dropped = 0                       # spans evicted from the ring
+        self._annotation = None                # jax.profiler.TraceAnnotation
 
     # -- configuration -------------------------------------------------------
     @property
@@ -242,9 +271,13 @@ class Tracer:
         if buffer is not None:
             if buffer < 1:
                 raise ValueError("trace buffer must be >= 1")
-            self._buffer = int(buffer)
             with self._lock:
-                self._evict()
+                if int(buffer) != self._buffer:
+                    self._buffer = int(buffer)
+                    kept = list(self._spans)[-self._buffer:]
+                    self.dropped += len(self._spans) - len(kept)
+                    self._spans = collections.deque(kept,
+                                                    maxlen=self._buffer)
         if sample_every is not None:
             if sample_every < 1:
                 raise ValueError("sample_every must be >= 1")
@@ -254,6 +287,8 @@ class Tracer:
                 raise ValueError("process label must be non-empty")
             self.process = str(process)
         _OFF = self.mode == "off"
+        if not _OFF and self._annotation is None:
+            self._annotation = _profiler_annotation()
         return self
 
     # -- span lifecycle ------------------------------------------------------
@@ -286,6 +321,9 @@ class Tracer:
                   parent_id=parent_id, trace_id=trace_id,
                   tid=t.ident or 0, thread_name=t.name,
                   t0=time.perf_counter(), args=dict(args), flow_from=flow)
+        if self._annotation is not None:
+            sp._mirror = self._annotation(name)
+            sp._mirror.__enter__()
         local.stack.append(sp)
         with self._lock:
             self._open[sp.span_id] = sp
@@ -293,6 +331,9 @@ class Tracer:
 
     def _finish(self, sp: Span) -> None:
         sp.t1 = time.perf_counter()
+        if sp._mirror is not None:
+            sp._mirror.__exit__(None, None, None)
+            sp._mirror = None
         stack = self._local.stack
         # normal case: sp is the innermost open span on this thread
         if stack and stack[-1] is sp:
@@ -301,15 +342,9 @@ class Tracer:
             stack.remove(sp)
         with self._lock:
             self._open.pop(sp.span_id, None)
+            if len(self._spans) == self._buffer:
+                self.dropped += 1              # the append evicts the oldest
             self._spans.append(sp)
-            self._evict()
-
-    def _evict(self) -> None:
-        # caller holds _lock
-        if len(self._spans) > self._buffer:
-            n = len(self._spans) - self._buffer
-            del self._spans[:n]
-            self.dropped += n
 
     # -- cross-thread parenting ----------------------------------------------
     def context(self) -> Optional[TraceContext]:
@@ -389,6 +424,15 @@ class _Attach:
         return False
 
 
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None when JAX is missing."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
 #: the process-global tracer every instrumentation site records into
 TRACER = Tracer()
 _OFF = True         # mirrors TRACER.mode — the one-load disabled check
@@ -399,6 +443,17 @@ def span(name: str, cat: str = "", **args):
     if _OFF:
         return NULL_SPAN
     return TRACER._start(name, cat, args)
+
+
+def current_span():
+    """The innermost open span of this thread, or :data:`NULL_SPAN` when
+    tracing is off, no span is open, or the open tree was not sampled."""
+    if _OFF:
+        return NULL_SPAN
+    local = TRACER._local
+    if local.suppress or not local.stack:
+        return NULL_SPAN
+    return local.stack[-1]
 
 
 def configure(**kw) -> Tracer:

@@ -13,6 +13,8 @@ fixed directory.
 
 import importlib.util
 import os
+import re
+import sys
 from pathlib import Path
 
 import jax
@@ -101,6 +103,46 @@ def test_fused_plan_compiles_for_v5e(one_chip, kind):
         assert "tpu_custom_call" in lowered.compile().as_text()
     finally:
         dr.clear_plan_cache()
+
+
+def _bench_kernels():
+    """The kernel names the benchmark's trace reduction knows."""
+    spec = importlib.util.spec_from_file_location(
+        "bench_trace", ROOT / "bench" / "harness" / "trace.py")
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules.setdefault(spec.name, mod)     # its dataclasses look it up
+    spec.loader.exec_module(mod)
+    return {n for names in mod.KERNELS.values() for n in names}
+
+
+@pytest.mark.parametrize("kind, module", [("rebucket", "shuffle_rebucket"),
+                                          ("scatter", "store_scatter")])
+def test_fused_plan_names_its_program_and_kernels(one_chip, kind, module):
+    """The trace finds the fused plans under their layer names and each
+    Pallas custom call under a kernel family's name."""
+    B, m = 1 << 16, PLAN_WORKERS
+    packs = tuple(_spec(one_chip, (B, width), jnp.dtype(dt))
+                  for dt, width in PACKS)
+    i32 = jnp.int32
+    try:
+        if kind == "rebucket":
+            plan = dr._fused_rebucket_plan(m, B, PACKS, False, True)
+            text = plan.fn.lower(_spec(one_chip, (B,), i32),
+                                 _spec(one_chip, (), i32),
+                                 packs).compile().as_text()
+        else:
+            plan = dr._fused_scatter_plan(m, B, B, PACKS, False, True)
+            text = plan.fn.lower(
+                _spec(one_chip, (B,), i32), _spec(one_chip, (m,), i32),
+                _spec(one_chip, (), i32), _spec(one_chip, (m,), i32),
+                packs).compile().as_text()
+    finally:
+        dr.clear_plan_cache()
+    assert re.search(rf"^HloModule jit_{module}\b", text, re.M)
+    calls = [re.sub(r"\.\d+$", "", ln.split(" = ", 1)[0].strip().lstrip("%"))
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls and set(calls) <= _bench_kernels(), calls
 
 
 # -- entry-point guards --------------------------------------------------------
