@@ -471,12 +471,17 @@ def _fused_scatter_plan(m: int, B: int, R: int, spec: Tuple,
                         interpret: bool, use_kernel: bool) -> ShufflePlan:
     """pids + counts + dynamic (n, slot offsets) + packs → flat (R, C) packs.
 
-    The per-partition destination base offsets ride along as a traced
-    ``(m,)`` array and the output rows are bucketed to ``R ≥ total slots``
-    (+1 trash slot), so same-shape writes with different key skew — and
-    uniform vs bucketed :class:`CapacityMap` layouts alike — reuse one
-    trace; the caller slices ``[:total]`` eagerly outside the jit.  The
-    uniform layout simply passes ``offsets = arange(m) * cap``."""
+    Counting-sort kernel → each row's slot ``flat_dest`` → one 1-D int32
+    scatter that inverts it into ``inv`` (the source row of every slot) →
+    one packed gather per dtype pack, as the rebucket and ``hostperm``
+    plans do: on the TPU a gather of wide rows is far cheaper than a
+    scatter of them.  The per-partition destination base offsets ride
+    along as a traced ``(m,)`` array and the output rows are bucketed to
+    ``R ≥ total slots`` (+1 trash slot), so same-shape writes with
+    different key skew — and uniform vs bucketed :class:`CapacityMap`
+    layouts alike — reuse one trace; the caller slices ``[:total]`` eagerly
+    outside the jit.  The uniform layout simply passes
+    ``offsets = arange(m) * cap``."""
     key = ("scatter", m, B, R, spec, interpret, use_kernel, "fused")
 
     def build(plan: ShufflePlan):
@@ -494,10 +499,12 @@ def _fused_scatter_plan(m: int, B: int, R: int, spec: Tuple,
             # the trash slot R (the clamped take is discarded by the where)
             base = jnp.take(slot_offs, jnp.minimum(pids, m - 1))
             flat_dest = jnp.where(pids < m, base + rank, R)
-            outs = tuple(
-                jnp.zeros((R + 1, p.shape[1]), p.dtype)
-                .at[flat_dest].set(p)[:R]
-                for p in packs)
+            # invert once, in 1-D: the source row of every output slot.
+            # Empty slots keep the out-of-range row B and gather zeros.
+            inv = jnp.full(R + 1, B, jnp.int32).at[flat_dest].set(
+                jnp.arange(B, dtype=jnp.int32))[:R]
+            outs = tuple(jnp.take(p, inv, axis=0, mode="fill", fill_value=0)
+                         for p in packs)
             return flat_dest, outs
         return store_scatter
 
@@ -667,9 +674,10 @@ def device_scatter_padded(flat_columns: Columns, pids, counts, *,
 
     One cached counting-sort plan per (bucket, dtype-set, m, row-bucket):
     destination slot of row i is ``base[pids[i]] + rank-of-i-within-its-
-    partition``, materialized per dtype *pack* — K same-dtype columns cost
-    one scatter.  Round-trippable columns come back device-resident (jax
-    arrays); 64-bit columns are scattered host-side (hybrid).
+    partition``, inverted into each slot's source row and materialized per
+    dtype *pack* — K same-dtype columns cost one gather.  Round-trippable
+    columns come back device-resident (jax arrays); 64-bit columns are
+    scattered host-side (hybrid).
 
     A ``capacity`` (or capacity-map bucket) smaller than its partition's
     row count would silently clamp/drop rows inside the scatter, so it

@@ -211,6 +211,72 @@ def test_fused_mode_matches_hostperm(use_kernel):
                                       np.asarray(sc_h[k]), err_msg=k)
 
 
+def _numpy_padded_layout(v, pids, m, offsets, total):
+    """Host placement: row i of partition p at slot offsets[p] + its stable
+    rank within p; every other slot is zero."""
+    order = np.argsort(pids, kind="stable")
+    counts = np.bincount(pids, minlength=m)
+    starts = np.cumsum(counts) - counts
+    rank = np.empty(len(pids), np.int64)
+    rank[order] = np.arange(len(pids)) - starts[pids[order]]
+    out = np.zeros((total,) + v.shape[1:], v.dtype)
+    out[offsets[pids] + rank] = v
+    return out
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+@pytest.mark.parametrize("case", ["full_bucket", "capacity_map_empty",
+                                  "u8_wide", "f64_hybrid"])
+def test_fused_scatter_layout_matches_hostperm(case, use_kernel):
+    """The fused store plan's gather-built layout ≡ the hostperm layout ≡
+    the numpy placement, bit for bit, with zeros in every empty slot: n filling
+    its bucket (no padding row), a bucketed CapacityMap with empty
+    partitions, a wide uint8 column, and a float64 column that rides the
+    plan's ``flat_dest`` host-side."""
+    from repro.data.capacity import CapacityMap, bucket_capacity
+    rng = np.random.default_rng(11)
+    m = 7
+    n = 512 if case == "full_bucket" else 300
+    if case == "full_bucket":
+        assert dr.shape_bucket(n) == n
+    cols = {"v": rng.normal(size=n).astype(np.float32),
+            "i": rng.integers(-50, 50, n).astype(np.int32)}
+    if case == "u8_wide":
+        cols["c"] = rng.integers(1, 256, (n, 44)).astype(np.uint8)
+    if case == "f64_hybrid":
+        cols["d"] = rng.normal(size=n)
+    # partitions 2 and 5 stay empty in the bucketed case
+    live = [p for p in range(m) if case != "capacity_map_empty"
+            or p not in (2, 5)]
+    pids = rng.choice(live, n).astype(np.int64)
+    counts = np.bincount(pids, minlength=m)
+    kw = {}
+    if case == "capacity_map_empty":
+        caps = [bucket_capacity(c) for c in counts]
+        caps[5] = 8                        # an empty partition with slots
+        cm = CapacityMap.of(caps)
+        assert cm.total_slots > n and caps[2] == 0
+        kw["capacity_map"] = cm
+        offsets, total = cm.offsets.astype(np.int64), cm.total_slots
+    else:
+        cap = int(counts.max()) + 3
+        kw["capacity"] = cap
+        offsets, total = np.arange(m, dtype=np.int64) * cap, m * cap
+    sc_f = dr.device_scatter_padded(cols, jnp.asarray(pids, jnp.int32),
+                                    counts, mode="fused", interpret=True,
+                                    use_kernel=use_kernel, **kw)
+    sc_h = dr.device_scatter_padded(cols, jnp.asarray(pids, jnp.int32),
+                                    counts, mode="hostperm", **kw)
+    for k, v in cols.items():
+        want = _numpy_padded_layout(v, pids, m, offsets, total)
+        if "capacity" in kw:
+            want = want.reshape((m, kw["capacity"]) + v.shape[1:])
+        got_f, got_h = np.asarray(sc_f[k]), np.asarray(sc_h[k])
+        assert got_f.dtype == got_h.dtype == v.dtype, k
+        np.testing.assert_array_equal(got_f, want, err_msg=k)
+        np.testing.assert_array_equal(got_h, want, err_msg=k)
+
+
 def test_chained_rebucket_relays_fresh_key():
     """Chained device repartitions: the relayed device_columns carry the
     previous shuffle's __key__, which must never shadow the key the next

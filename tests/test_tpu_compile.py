@@ -84,25 +84,47 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, m):
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
-@pytest.mark.parametrize("kind", ["rebucket", "scatter"])
-def test_fused_plan_compiles_for_v5e(one_chip, kind):
-    B, m = PLAN_BUCKET, PLAN_WORKERS
-    packs = tuple(_spec(one_chip, (B, width), jnp.dtype(dt))
+def _compiled_plan(sharding, kind, B):
+    """The optimized HLO text of one fused plan over PACKS at bucket B."""
+    m, i32 = PLAN_WORKERS, jnp.int32
+    packs = tuple(_spec(sharding, (B, width), jnp.dtype(dt))
                   for dt, width in PACKS)
-    i32 = jnp.int32
     try:
         if kind == "rebucket":
             plan = dr._fused_rebucket_plan(m, B, PACKS, False, True)
-            lowered = plan.fn.lower(_spec(one_chip, (B,), i32),
-                                    _spec(one_chip, (), i32), packs)
+            lowered = plan.fn.lower(_spec(sharding, (B,), i32),
+                                    _spec(sharding, (), i32), packs)
         else:
             plan = dr._fused_scatter_plan(m, B, B, PACKS, False, True)
             lowered = plan.fn.lower(
-                _spec(one_chip, (B,), i32), _spec(one_chip, (m,), i32),
-                _spec(one_chip, (), i32), _spec(one_chip, (m,), i32), packs)
-        assert "tpu_custom_call" in lowered.compile().as_text()
+                _spec(sharding, (B,), i32), _spec(sharding, (m,), i32),
+                _spec(sharding, (), i32), _spec(sharding, (m,), i32), packs)
+        return lowered.compile().as_text()
     finally:
         dr.clear_plan_cache()
+
+
+@pytest.mark.parametrize("kind", ["rebucket", "scatter"])
+def test_fused_plan_compiles_for_v5e(one_chip, kind):
+    assert "tpu_custom_call" in _compiled_plan(one_chip, kind, PLAN_BUCKET)
+
+
+def test_fused_scatter_plan_gathers_its_packs(one_chip):
+    """The store plan builds its layout with one gather per pack: no scatter
+    of wide rows, at most one 1-D int32 scatter (the inversion of each row's
+    slot), and the counting-sort kernel still in place."""
+    text = _compiled_plan(one_chip, "scatter", PLAN_BUCKET)
+    ops = re.findall(r"= (\w+)\[([\d,]*)\]\S* (scatter|gather)\(", text)
+    scatters = [(dt, dims) for dt, dims, op in ops if op == "scatter"]
+    assert len(scatters) <= 1, scatters
+    assert all(dt == "s32" and "," not in dims for dt, dims in scatters), \
+        scatters
+    row_gathers = sorted(dt for dt, dims, op in ops
+                         if op == "gather" and "," in dims)
+    hlo_dtype = {"float32": "f32", "int32": "s32"}
+    assert row_gathers == sorted(hlo_dtype[dt] for dt, _w in PACKS), ops
+    assert re.search(r"%scatter_perm(\.\d+)? = .*"
+                     r'custom_call_target="tpu_custom_call"', text)
 
 
 def _bench_kernels():
