@@ -4,6 +4,7 @@ compile meter and the result line."""
 from __future__ import annotations
 
 import gc
+import importlib.util
 import json
 import os
 import resource
@@ -45,6 +46,23 @@ def find_cell(man: dict, name: str) -> Tuple[dict, dict, dict]:
 
 def load_traffic(name: str) -> dict:
     return load_json(BENCH / "traffic" / f"{name}.json")
+
+
+def load_module(folder: str, name: str):
+    """The module ``bench/<folder>/<name>.py``.  This is how the harness
+    finds what a name in ``BENCHMARK.json`` or in a configuration or
+    traffic file stands for: a traffic kind (``kinds``), a population
+    (``populations``) or a metric's reader (``metrics``).  A name with no
+    file exits, naming the file."""
+    path = BENCH / folder / f"{name}.py"
+    if not path.is_file():
+        raise SystemExit(f"bench: no file {path.relative_to(ROOT)} for "
+                         f"{name!r} in {folder}/")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{folder}_{name}".replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class CompileMeter:

@@ -5,7 +5,7 @@ its kind, no traced event), and the harness leaves the metric out."""
 
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import Callable, List, Optional, Sequence
 
 from . import roofline
 from . import trace as tr
@@ -35,27 +35,29 @@ def idle_pct(run: Run) -> Optional[float]:
     return 100.0 * (1.0 - tr.busy_s(run.trace, lo, hi) / (hi - lo))
 
 
-def roofline_pct(run: Run, family: str) -> Optional[float]:
-    """The kernel family's share of its roofline over the window: the
-    bytes its algorithm needs for every dispatch of the window at the
-    chip's peak bandwidth, over the device time of its events.
+def roofline_pct(run: Run, events: Sequence[str],
+                 nbytes: Callable[[dict], int], span: str
+                 ) -> Optional[float]:
+    """A kernel's share of its bandwidth roofline over the window: the
+    bytes its algorithm needs for every call of the window at the chip's
+    peak bandwidth, over the device time of its events.
 
-    Each ``shuffle.dispatch`` span (its ``rows`` and ``m``) launches one
-    kernel of each family, so the i-th event goes with the i-th span; a
-    window whose counts differ is not read."""
+    ``events`` are the names the kernel's device events go by
+    (``trace.kernel_name``).  Each program span ``span`` launches one
+    call, which needs ``nbytes(span.args)`` bytes, so the i-th event goes
+    with the i-th span; a window whose counts differ is not read."""
     if run.trace is None:
         return None
     lo, hi = run.window
-    events = tr.kernel_events(run.trace, family, lo, hi)
-    disp = sorted(run.spans_named("shuffle.dispatch"), key=lambda s: s.t0)
-    if not events:
+    found = tr.kernel_events(run.trace, events, lo, hi)
+    calls = sorted(run.spans_named(span), key=lambda s: s.t0)
+    if not found:
         return None
-    if len(events) != len(disp):
-        log(f"{family}: {len(events)} kernel events but {len(disp)} "
-            f"dispatches in the window; roofline not read")
+    if len(found) != len(calls):
+        log(f"{events[0]}: {len(found)} kernel events but {len(calls)} "
+            f"{span} spans in the window; roofline not read")
         return None
-    nbytes = sum(roofline.BYTES[family](int(s.args["rows"]),
-                                        int(s.args["m"])) for s in disp)
-    device_s = sum(e.dur_s for e in events)
+    total = sum(nbytes(s.args) for s in calls)
+    device_s = sum(e.dur_s for e in found)
     bw = roofline.peaks(run.device_kind)["hbm_bytes_per_s"]
-    return 100.0 * (nbytes / bw) / device_s
+    return 100.0 * (total / bw) / device_s

@@ -14,8 +14,9 @@ that the benchmark holds the system to:
   power-of-two capacity of each worker's count needs at most
   ``threshold`` of the uniform power-of-two total.
 
-Every float column is exact to a binary fraction (``tables.py``), so the
-sums are exact and the comparison is bit for bit.
+Every float column is exact to a binary fraction
+(``populations/tpch.py``), so the sums are exact and the comparison is
+bit for bit.
 """
 
 from __future__ import annotations

@@ -6,6 +6,8 @@ kernels do a few integer operations per key, far under any compute
 peak) divided by the device time its events took.  The bytes count what
 the algorithm must read and write for ``n`` valid rows over ``m``
 partitions, not what an implementation pads, re-reads or scratches.
+Each function takes the arguments of the span that launches one call;
+a kernel added later keeps its function in its own metric reader.
 """
 
 from __future__ import annotations
@@ -28,17 +30,17 @@ def peaks(device_kind: str) -> Dict[str, float]:
                        f"published numbers to PEAKS") from None
 
 
-def hash_partition_bytes(n: int, m: int) -> int:
-    """Keys in (int32), a partition id out per key (int32), and the
+def hash_partition_bytes(args: dict) -> int:
+    """Per ``shuffle.dispatch`` of ``rows`` keys over ``m`` partitions:
+    keys in (int32), a partition id out per key (int32), and the
     ``m``-bin histogram out (int32)."""
+    n, m = int(args["rows"]), int(args["m"])
     return 4 * n + 4 * n + 4 * m
 
 
-def scatter_perm_bytes(n: int, m: int) -> int:
-    """Partition ids in (int32), the ``m`` base offsets in (int32), a
+def scatter_perm_bytes(args: dict) -> int:
+    """Per ``shuffle.dispatch`` of ``rows`` keys over ``m`` partitions:
+    partition ids in (int32), the ``m`` base offsets in (int32), a
     destination slot out per row (int32)."""
+    n, m = int(args["rows"]), int(args["m"])
     return 4 * n + 4 * m + 4 * n
-
-
-BYTES = {"hash_partition": hash_partition_bytes,
-         "scatter_perm": scatter_perm_bytes}

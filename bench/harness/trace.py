@@ -12,9 +12,10 @@ The reduction here is the one every pull request is measured with:
 * busy: the union of the device's operation intervals inside the window;
 * kernel time: the summed device duration of a kernel's events.  On a
   TPU an operation's event is named by its HLO text
-  (``%scatter_permutation.1 = s32[...] custom-call(...)``); a kernel is a
-  custom call whose instruction name, less its ``.N`` suffix, is one of
-  the names in ``KERNELS``;
+  (``%scatter_permutation.1 = s32[...] custom-call(...)``); a kernel's
+  events are the custom calls whose instruction name, less its ``.N``
+  suffix, is one of the names its reader gives (``KERNELS`` names the
+  families the breakdown labels);
 * idle gaps: the stretches of the window with no operation on the
   device, each named by the innermost host span open at its midpoint.
 """
@@ -132,16 +133,23 @@ def kernel_family(name: str) -> Optional[str]:
     return None
 
 
-def event_kernel(ev: DeviceEvent) -> Optional[str]:
-    """The kernel family of a device event: a custom call named after a
-    kernel, or an event named as one."""
+def kernel_name(ev: DeviceEvent) -> Optional[str]:
+    """The name a device event goes by as a kernel: a custom call's
+    instruction name less its ``.N`` suffix, or the event's own name where
+    it is not HLO text; ``None`` for any other operation."""
     parts = hlo_parts(ev.name)
     if parts is None:
-        return kernel_family(ev.name)
+        return ev.name
     instr, _type, opcode = parts
     if opcode != "custom-call":
         return None
-    return kernel_family(re.sub(r"\.\d+$", "", instr))
+    return re.sub(r"\.\d+$", "", instr)
+
+
+def event_kernel(ev: DeviceEvent) -> Optional[str]:
+    """The kernel family (``KERNELS``) of a device event, for labels."""
+    name = kernel_name(ev)
+    return None if name is None else kernel_family(name)
 
 
 def op_label(ev: DeviceEvent, module: str = "") -> str:
@@ -229,10 +237,12 @@ def busy_s(trace: Trace, lo: float, hi: float) -> float:
     return sum(per) / len(per)
 
 
-def kernel_events(trace: Trace, family: str, lo: float, hi: float
+def kernel_events(trace: Trace, names: Sequence[str], lo: float, hi: float
                   ) -> List[DeviceEvent]:
+    """The window's events of the kernel whose events go by ``names``
+    (``kernel_name``), in order of start."""
     return sorted((e for e in trace.events
-                   if lo <= e.start_s < hi and event_kernel(e) == family),
+                   if lo <= e.start_s < hi and kernel_name(e) in names),
                   key=lambda e: e.start_s)
 
 

@@ -1,4 +1,5 @@
-"""TPC-H tables at the specification's full row width, made in numpy.
+"""The ``tpch`` population: TPC-H tables at the specification's full row
+width, made in numpy.
 
 Population follows TPC-H clause 4.2.3 (row counts, key rules, value
 ranges), with three encodings that the store needs: every key and date
@@ -10,10 +11,11 @@ Prices are kept to exact binary fractions (retail and extended price to
 result does not depend on the order of summation.
 
 The population is fixed, as dbgen's is for a scale factor: it comes
-from the configuration's ``population_seed``.  A run's ``--seed`` permutes
-the rows of every table, so every seed stores and queries the same rows
-(the same sizes at every step, hence the same compiled programs) in
-another order, and the same seed gives the same tables.
+from the configuration's ``scale_factor`` and ``population_seed``.  A
+run's ``--seed`` permutes the rows of every table, so every seed stores
+and queries the same rows (the same sizes at every step, hence the same
+compiled programs) in another order, and the same seed gives the same
+tables.
 """
 
 from __future__ import annotations
@@ -77,11 +79,11 @@ def _custkeys(rng, n: int, n_cust: int) -> np.ndarray:
     return (3 * (k // 2) + 1 + k % 2).astype(np.int32)
 
 
-def make_tables(sf: float, seed: int, want=("lineitem", "orders", "part"),
-                population_seed: int = 0) -> Dict[str, Table]:
-    """The population of ``population(...)``, each table's rows permuted
-    by ``seed``."""
-    tables = population(sf, want, population_seed)
+def make_tables(config: dict, seed: int, want) -> Dict[str, Table]:
+    """The tables ``want`` of the configuration's population, each
+    table's rows permuted by ``seed``."""
+    tables = population(config["scale_factor"], want,
+                        config["population_seed"])
     rng = np.random.default_rng(seed)
     for name in sorted(tables):
         t = tables[name]
@@ -186,15 +188,3 @@ def population(sf: float, want=("lineitem", "orders", "part"),
     tables = {"lineitem": lineitem, "orders": orders, "part": part}
     return {k: tables[k] for k in want}
 
-
-def schema_of(tables: Dict[str, Table]) -> Dict[str, Dict[str, list]]:
-    """``{table: {column: [dtype, width]}}`` — what a configuration file
-    states, to check the generated tables against."""
-    return {t: {k: [str(v.dtype), int(np.prod(v.shape[1:]))]
-                for k, v in cols.items()}
-            for t, cols in tables.items()}
-
-
-def row_bytes(table: Table) -> int:
-    return int(sum(v.itemsize * int(np.prod(v.shape[1:]))
-                   for v in table.values()))

@@ -5,9 +5,7 @@
 
 from the root of a checkout, on a machine whose first JAX device is a TPU
 with as many chips as the cell asks for; anything else exits nonzero
-before any work.  The cell (``BENCHMARK.json``) names a configuration
-file and a traffic file; the traffic file's ``kind`` picks the general
-generator in ``harness/generators.py``.  A run:
+before any work.  A run:
 
 1. sets up: tables from ``--seed``, the stored layouts, every shape of
    the cell's own traffic warmed (compiles count here, in ``setup_s``);
@@ -17,6 +15,33 @@ generator in ``harness/generators.py``.  A run:
    against the numpy reference;
 4. prints its notes and every compared number on standard error, and
    one JSON result line last on standard output.
+
+Everything a cell is made of is found by its name in ``BENCHMARK.json``
+or in the files it names, so a configuration, a population, a traffic
+kind and a metric are added as new files and new entries, with no file
+of the harness changed (``core.load_module`` finds them; a name with no
+file exits nonzero, naming the file):
+
+* a configuration is ``bench/configs/<name>.json`` (its ``file`` entry):
+  the sizes as run, its ``population``, the ``store`` settings, the
+  ``tables`` it holds as ``{table: {column: [dtype, width]}}`` (checked
+  against what the population made), and ``cpu_test``, the keys the CPU
+  tests override to run it at a tiny size;
+* a population is ``bench/populations/<population>.py``, defining
+  ``make_tables(config, seed, want)``: the tables named in ``want``, as
+  ``{table: {column: numpy array}}``, the same for the same seed;
+* a traffic mix is ``bench/traffic/<name>.json``, data whose ``kind``
+  names ``bench/kinds/<kind>.py``, which defines ``GENERATOR``: a
+  subclass of ``harness.generators.Generator`` with ``setup``, ``warm``,
+  ``window``, ``end_to_end`` (the cell's end-to-end metrics by name),
+  ``check`` (``Run.check`` for each compared number) and, where it keeps
+  more state, ``release``;
+* a per-layer metric is ``bench/metrics/<metric>.py``, defining
+  ``read(run)``: the metric from the run's units, spans, counters or
+  trace, or ``None`` where the run holds nothing to read.  A kernel's
+  roofline reader passes ``harness.readers.roofline_pct`` the names of
+  the kernel's device events, the span that launches one call and its
+  own function of that span's arguments to the bytes a call needs.
 """
 
 from __future__ import annotations
@@ -27,7 +52,6 @@ T_START = time.perf_counter()
 
 import argparse  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import tempfile  # noqa: E402
@@ -67,12 +91,7 @@ def require_chips(n: int):
 
 
 def metric_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return core.load_module("metrics", name).read
 
 
 def cell_metrics(man: dict, cell: str, kind: str):
@@ -100,12 +119,12 @@ def main(argv=None, *, devices=None, config=None) -> int:
     cell, _entry, stated = core.find_cell(man, args.workload)
     config = dict(stated, **(config or {}))
     traffic = core.load_traffic(cell["traffic"])
+    generator = core.load_module("kinds", traffic["kind"]).GENERATOR
 
     import jax
     devs = devices if devices is not None else require_chips(cell["chips"])
     cache = core.enable_compile_cache()
     meter = core.CompileMeter()
-    from harness.generators import GENERATORS
     from repro.data import device_repartition as dr
     log(f"device: {devs[0].platform} {devs[0].device_kind} x{len(devs)}; "
         f"ShufflePlan defaults mode={dr.default_mode()} "
@@ -115,7 +134,7 @@ def main(argv=None, *, devices=None, config=None) -> int:
     run = Run(cell=cell, config=config, traffic=traffic, seed=args.seed,
               seconds=args.seconds, traced=bool(args.trace),
               device_kind=devs[0].device_kind)
-    gen = GENERATORS[traffic["kind"]](run)
+    gen = generator(run)
     gen.setup()
     gen.warm()
     setup_s = time.perf_counter() - T_START

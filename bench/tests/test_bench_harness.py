@@ -27,9 +27,23 @@ from harness import core  # noqa: E402
 
 MAN = json.loads((ROOT / "BENCHMARK.json").read_text())
 CELLS = [c["name"] for c in MAN["workloads"]]
-TINY = {"scale_factor": 0.003}
+CONFIGS = {c["name"]: json.loads((ROOT / c["file"]).read_text())
+           for c in MAN["configs"]}
+CELL = {c["name"]: c for c in MAN["workloads"]}
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+
+
+def config_of(cell):
+    return CONFIGS[CELL[cell]["config"]]
+
+
+def cells_of(kind, population=None):
+    """The cells whose traffic is of ``kind`` (and whose configuration's
+    population is ``population``, where one is given)."""
+    return [c for c in CELLS
+            if core.load_traffic(CELL[c]["traffic"])["kind"] == kind
+            and population in (None, config_of(c)["population"])]
 
 
 # ---------------------------------------------------------------------------
@@ -82,8 +96,12 @@ def test_manifest_files_live_under_its_paths():
         stated = json.loads((ROOT / c["file"]).read_text())
         assert stated["name"] == c["name"]
         assert sorted(stated["reduced"]) == sorted(c["reduced"])
+        assert (BENCH / "populations" / f"{stated['population']}.py").exists()
+        assert isinstance(stated["cpu_test"], dict)
     for c in MAN["workloads"]:
         assert (BENCH / "traffic" / f"{c['traffic']}.json").exists()
+        kind = core.load_traffic(c["traffic"])["kind"]
+        assert (BENCH / "kinds" / f"{kind}.py").exists(), kind
     for m in MAN["per_layer"]:
         assert (BENCH / "metrics" / f"{m['name']}.py").exists(), m["name"]
 
@@ -151,14 +169,16 @@ def test_run_refuses_a_checkout_without_the_program(tmp_path):
 @pytest.fixture
 def cpu_run(monkeypatch, capsys):
     """Run a cell through ``run.main`` (or the control's ``main``) on the
-    CPU at a tiny scale; the persistent compile cache is left alone."""
+    CPU at the tiny size its configuration's ``cpu_test`` states; the
+    persistent compile cache is left alone."""
     import jax
     monkeypatch.setattr(core, "enable_compile_cache", lambda: "(off)")
 
     def go(cell, trace=0, seconds=0.3, seed=4294967311, entry=bench_run):
         rc = entry.main(["--workload", cell, "--seed", str(seed),
                          "--seconds", str(seconds), "--trace", str(trace)],
-                        devices=jax.devices(), config=TINY)
+                        devices=jax.devices(),
+                        config=config_of(cell)["cpu_test"])
         assert rc == 0
         out = capsys.readouterr()
         return json.loads(out.out.strip().splitlines()[-1]), out.err
@@ -221,7 +241,7 @@ def _half_batch_aggregate(orig):
 
 
 @pytest.mark.parametrize("fault", ["altered", "half_batch"])
-@pytest.mark.parametrize("cell", [c for c in CELLS if ".query" in c])
+@pytest.mark.parametrize("cell", cells_of("closed_queries"))
 def test_query_faults_come_out_not_correct(cpu_run, monkeypatch, cell,
                                            fault):
     from repro.core.executor import Executor
@@ -253,6 +273,7 @@ def test_ingest_faults_come_out_not_correct(cpu_run, monkeypatch, fault):
         orig = PartitionStore._dispatch_device
 
         def dispatch(self, data, partitioner, n, seed):
+            # TPC-H columns: this fault runs on the tpch cells alone
             data = dict(data)
             q = np.asarray(data["l_quantity" if "l_quantity" in data
                                 else "o_totalprice"]).copy()
@@ -261,12 +282,15 @@ def test_ingest_faults_come_out_not_correct(cpu_run, monkeypatch, fault):
                  else "o_totalprice"] = q
             return orig(self, data, partitioner, n, seed)
         monkeypatch.setattr(PartitionStore, "_dispatch_device", dispatch)
-    (cell,) = [c for c in CELLS if c.endswith(".ingest")]
-    res, _ = cpu_run(cell)
-    assert res["correct"] is False
-    bad = {k: v["value"] for k, v in res["checks"].items()
-           if k.startswith("mismatched")}
-    assert sum(bad.values()) > 0, bad
+    cells = cells_of("closed_ingest",
+                     "tpch" if fault == "altered" else None)
+    assert cells
+    for cell in cells:
+        res, _ = cpu_run(cell)
+        assert res["correct"] is False, cell
+        bad = {k: v["value"] for k, v in res["checks"].items()
+               if k.startswith("mismatched")}
+        assert sum(bad.values()) > 0, (cell, bad)
 
 
 @pytest.mark.parametrize("cell", CELLS)

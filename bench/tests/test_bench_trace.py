@@ -11,7 +11,7 @@ import pytest
 BENCH = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(BENCH.parent / "src"), str(BENCH)]
 
-from harness import readers, roofline  # noqa: E402
+from harness import core, readers, roofline  # noqa: E402
 from harness import trace as tr  # noqa: E402
 from harness.core import Run, Span, Unit  # noqa: E402
 
@@ -36,10 +36,10 @@ def test_busy_kernels_top_ops_and_idle_attribution():
                          ev("copy.1", 3.0, 1.0)],
                  devices=["/device:TPU:0"], host_spans=[])
     assert tr.busy_s(t, 0.0, 5.0) == pytest.approx(2.1)
-    assert [e.name for e in tr.kernel_events(t, "hash_partition", 0, 5)] \
-        == ["_kernel_padded"]
-    assert [e.name for e in tr.kernel_events(t, "scatter_perm", 0, 5)] \
-        == ["_perm_kernel"]
+    for fam, name in (("hash_partition", "_kernel_padded"),
+                      ("scatter_perm", "_perm_kernel")):
+        found = tr.kernel_events(t, tr.KERNELS[fam], 0, 5)
+        assert [e.name for e in found] == [name]
     assert tr.top_ops(t, 0.0, 5.0)[0] == ["copy.1", 1.0]
     spans = [("exec.join", 0.0, 0.9), ("exec.run", 0.0, 4.9),
              ("exec.aggregate", 2.2, 2.9)]
@@ -106,7 +106,7 @@ def test_peaks_table_refuses_an_unknown_chip():
 
 def test_roofline_share_from_dispatch_spans_and_kernel_events():
     n, m = 1 << 20, 32
-    per_call = roofline.hash_partition_bytes(n, m) / 819e9
+    per_call = roofline.hash_partition_bytes({"rows": n, "m": m}) / 819e9
     run = Run(cell={}, config={}, traffic={}, seed=0, seconds=1.0,
               traced=True, device_kind="TPU v5 lite")
     run.window = (0.0, 10.0)
@@ -117,12 +117,14 @@ def test_roofline_share_from_dispatch_spans_and_kernel_events():
     run.trace = tr.Trace(events=[ev("_kernel_padded", 1.1 + i, 4 * per_call)
                                  for i in range(2)],
                          devices=["/device:TPU:0"], host_spans=[])
-    assert readers.roofline_pct(run, "hash_partition") == pytest.approx(25.0)
+    hashp, perm = (core.load_module("metrics", f"{k}_roofline.query").read
+                   for k in ("hash_partition", "scatter_perm"))
+    assert hashp(run) == pytest.approx(25.0)
     # no scatter_perm events: nothing to read, not a zero
-    assert readers.roofline_pct(run, "scatter_perm") is None
+    assert perm(run) is None
     # an event without its dispatch is not paired up
     run.spans = run.spans[:1]
-    assert readers.roofline_pct(run, "hash_partition") is None
+    assert hashp(run) is None
 
 
 def test_idle_share_and_span_readers():
