@@ -1,5 +1,6 @@
 # Lachesis core: the paper's primary contribution.
 #   ir, dsl          — analyzable/executable graph IR for UDF workloads
+#   reference        — plain numpy references of canned workloads (tests)
 #   partitioner      — two-terminal candidate extraction (Alg. 1+2)
 #   matching         — path-signature subgraph matching (Alg. 4)
 #   history          — workflow analyzer + skeleton graph (§3.1.1)
@@ -13,7 +14,8 @@
 #   sharding_bridge  — partitionings ⇄ JAX NamedShardings (TPU adaptation)
 
 from .ir import IRGraph, Node
-from .dsl import Workload, author_integrator, pagerank_iteration, matmul_workload
+from .dsl import (Workload, author_integrator, pagerank_iteration,
+                  pagerank_edges, matmul_workload)
 from .partitioner import (PartitionerCandidate, enumerate_candidates,
                           keyless_candidates, search, merge, dedupe,
                           HASH, RANGE, ROUND_ROBIN, RANDOM)

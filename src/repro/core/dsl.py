@@ -19,6 +19,8 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, List, Optional
 
+import numpy as np
+
 from .ir import IRGraph
 
 
@@ -211,6 +213,54 @@ def pagerank_iteration() -> Workload:
     contrib = wl.flatten(wl.map(j, fn=None, tag="emit_contribs"))
     agg = wl.aggregate(contrib, key=contrib["url"], reducer="sum")
     new_ranks = wl.map(agg, fn=None, tag="finish_ranks")  # damping + rename
+    wl.write(new_ranks, "ranks")
+    return wl
+
+
+def _emit_contribs(cols):
+    """Join of an edge with its source's rank → the contribution the edge
+    carries to its destination, under the aggregate's key.  Only the key
+    and ``contrib`` leave: a keyed aggregate sums every other column."""
+    return {"key": cols["dst"],
+            "contrib": cols["rank"] / cols["outdeg"].astype(np.float32)}
+
+
+def _damping(num_vertices: int, damping: float):
+    """The Graphalytics update over every vertex's summed contributions,
+    with the ranks of vertices that have no out-edge (dangling) spread
+    uniformly; float32 throughout, the dangling sum in float64."""
+    def damp(cols):
+        rank, outdeg = cols["rank"], cols["outdeg"]
+        dangling = float(rank[outdeg == 0].sum(dtype=np.float64))
+        base = np.float32((1.0 - damping) / num_vertices
+                          + damping * dangling / num_vertices)
+        return {"vid": cols["vid"],
+                "rank": base + np.float32(damping) * cols["contrib"],
+                "outdeg": outdeg}
+    return damp
+
+
+def pagerank_edges(num_vertices: int, damping: float = 0.85) -> Workload:
+    """One iteration of LDBC Graphalytics PageRank over an edge list:
+    edges(src, dst) ⋈ ranks(vid, rank, outdeg) on src = vid emits
+    ``rank / outdeg`` keyed on dst, a keyed sum adds them up, the sums
+    are joined back onto every vertex of ``ranks`` and the damping step
+    writes the new ``ranks``.  Both joins have a unique right side, and
+    ``ranks`` stays the left side of the second so that the written
+    layout keeps its stored partitioner.  A vertex with no in-edge drops
+    out of the second (inner) join."""
+    wl = Workload("pagerank-edges")
+    edges = wl.scan("edges")
+    ranks = wl.scan("ranks")
+    contribs = wl.join(edges, ranks, left_key=edges["src"],
+                       right_key=ranks["vid"], projection=_emit_contribs,
+                       tag="pr_emit")
+    sums = wl.aggregate(contribs, key=contribs["key"], reducer="sum")
+    current = wl.scan("ranks")
+    joined = wl.join(current, sums, left_key=current["vid"],
+                     right_key=sums["key"], tag="pr_gather")
+    new_ranks = wl.map(joined, fn=_damping(num_vertices, damping),
+                       tag="pr_damping")
     wl.write(new_ranks, "ranks")
     return wl
 

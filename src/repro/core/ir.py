@@ -189,10 +189,13 @@ class IRGraph:
         return [n.id for n in self.nodes.values() if n.is_partition]
 
     def find_scanner(self, dataset: str) -> Optional[int]:
-        for nid in self.scans:
-            if self.nodes[nid].params.get("dataset") == dataset:
-                return nid
-        return None
+        scanners = self.find_scanners(dataset)
+        return scanners[0] if scanners else None
+
+    def find_scanners(self, dataset: str) -> List[int]:
+        """Every scan node of ``dataset`` (a workload may scan it twice)."""
+        return [nid for nid in self.scans
+                if self.nodes[nid].params.get("dataset") == dataset]
 
     # -- structure -----------------------------------------------------------
     def toposort(self, within: Optional[Set[int]] = None) -> List[int]:

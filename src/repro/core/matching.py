@@ -32,20 +32,19 @@ def partitioning_match(f_D: Optional[PartitionerCandidate], dataset: str,
     if f_D is None or not f_D.is_keyed:
         return MatchResult(False, [])
     ssset_D = f_D.signature_set()
-    s_D = a.find_scanner(dataset)
-    if s_D is None:
-        return MatchResult(False, [])
-
     matched_nodes: List[int] = []
     checked = 0
-    # candidate isomorphic subgraphs = merged two-terminal subgraphs from the
-    # same scan node; reuse Alg. 1+2 to construct IG^(s_D, p_i)
-    for cand in merge(a, search(a, s_D)):
-        checked += 1
-        # the strategy label participates in the signature via the partition
-        # node token, so hash vs range partitionings never cross-match
-        if cand.signature_set() == ssset_D:
-            matched_nodes.append(cand.origin[1])
+    # candidate isomorphic subgraphs = merged two-terminal subgraphs from
+    # each scan node of the dataset (a workload may scan it more than
+    # once); reuse Alg. 1+2 to construct IG^(s_D, p_i)
+    for s_D in a.find_scanners(dataset):
+        for cand in merge(a, search(a, s_D)):
+            checked += 1
+            # the strategy label participates in the signature via the
+            # partition node token, so hash vs range partitionings never
+            # cross-match
+            if cand.signature_set() == ssset_D:
+                matched_nodes.append(cand.origin[1])
     return MatchResult(bool(matched_nodes), matched_nodes, checked)
 
 

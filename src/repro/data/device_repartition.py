@@ -248,6 +248,21 @@ def host_counting_sort_dest(pids: np.ndarray, counts: np.ndarray,
     return np.asarray(dest_offsets, dtype=np.int64)[pids] + rank
 
 
+def host_presorted_dest(counts: np.ndarray, cap: int,
+                        dest_offsets: Optional[np.ndarray] = None
+                        ) -> np.ndarray:
+    """The same placement for rows already segmented per worker (a
+    ``write_layout``): no sort, the worker of each row is implied by the
+    segmentation.  A bucketed layout passes its ``dest_offsets``."""
+    m = counts.shape[0]
+    pids = np.repeat(np.arange(m, dtype=np.int64), counts)
+    offsets = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    rank = np.arange(pids.shape[0], dtype=np.int64) - offsets[pids]
+    if dest_offsets is None:
+        return pids * cap + rank
+    return np.asarray(dest_offsets, dtype=np.int64)[pids] + rank
+
+
 # ---------------------------------------------------------------------------
 # Shape buckets and column packing
 # ---------------------------------------------------------------------------
@@ -529,6 +544,31 @@ def _hostperm_scatter_plan(m: int, B: int, R: int,
     return _get_plan(key, build)
 
 
+def _fused_place_plan(m: int, B: int, R: int, spec: Tuple) -> ShufflePlan:
+    """counts + slot offsets + packs → flat (R, C) packs, for rows already
+    segmented per worker: slot ``r`` of partition ``w`` takes row
+    ``offsets[w] + (r - slot_offs[w])`` while that is under ``counts[w]``,
+    so the layout is one packed gather per dtype, with no counting sort
+    and no inversion.  Keyed like the scatter plan: the offsets are
+    traced, the output rows bucketed to ``R``."""
+    key = ("place", m, B, R, spec, "fused")
+
+    def build(plan: ShufflePlan):
+        def store_place(counts, slot_offs, packs):
+            plan.traces += 1
+            slot = jnp.arange(R, dtype=jnp.int32)
+            w = jnp.searchsorted(slot_offs, slot, side="right") - 1
+            rank = slot - jnp.take(slot_offs, w)
+            offs = jnp.cumsum(counts) - counts
+            src = jnp.where(rank < jnp.take(counts, w),
+                            jnp.take(offs, w) + rank, B)
+            return tuple(jnp.take(p, src, axis=0, mode="fill", fill_value=0)
+                         for p in packs)
+        return store_place
+
+    return _get_plan(key, build)
+
+
 # ---------------------------------------------------------------------------
 # Re-bucket (engine repartition node)
 # ---------------------------------------------------------------------------
@@ -609,6 +649,8 @@ def device_rebucket_full(columns: Columns, key_vals, num_partitions: int, *,
                              tuple(upload(p.data) for p in packs))
             with _span("shuffle.fetch", "shuffle"):
                 outs_np = fetch_tree(outs_d, sp)
+        # the worker that holds most rows, from the fetched histogram
+        sp.set(max_worker_rows=int(counts_np.max()))
 
     out: Columns = {}
     device_out: Columns = {}
@@ -654,6 +696,95 @@ def _check_overflow(counts_np: np.ndarray, capacities: np.ndarray) -> None:
             f"for partition {pid}, e.g. via CapacityMap.from_counts)")
 
 
+@dataclass
+class _Layout:
+    """Where a padded write puts its rows: partition ``i`` holds
+    ``counts[i]`` rows from flat slot ``offsets[i]`` on, in ``total``
+    slots, shaped ``(m, cap, ...)`` (uniform) or flat ``(total, ...)``
+    (under a :class:`CapacityMap`)."""
+    counts: np.ndarray              # (m,) int64
+    offsets: np.ndarray             # (m,) int64
+    total: int
+    cap: int
+    bucketed: bool
+
+    @property
+    def m(self) -> int:
+        return int(self.counts.shape[0])
+
+    @property
+    def n(self) -> int:
+        return int(self.counts.sum())
+
+    def shape(self, trail: Tuple[int, ...]) -> Tuple[int, ...]:
+        if self.bucketed:
+            return (self.total,) + trail
+        return (self.m, self.cap) + trail
+
+
+def _plan_layout(counts, capacity: Optional[int],
+                 capacity_map: Optional[CapacityMap]) -> _Layout:
+    """The layout of a padded write, checked against its counts."""
+    counts_np = np.asarray(counts).astype(np.int64)
+    m = int(counts_np.shape[0])
+    max_count = int(counts_np.max()) if counts_np.sum() else 0
+    if capacity_map is not None:
+        if capacity is not None:
+            raise ValueError("pass capacity or capacity_map, not both")
+        if capacity_map.num_partitions != m:
+            raise ValueError(
+                f"capacity_map covers {capacity_map.num_partitions} "
+                f"partitions, counts cover {m}")
+        _check_overflow(counts_np, capacity_map.capacities)
+        return _Layout(counts_np, capacity_map.offsets.astype(np.int64),
+                       capacity_map.total_slots, 0, True)
+    if capacity is not None and int(capacity) < max_count:
+        _check_overflow(counts_np, np.full(m, int(capacity), dtype=np.int64))
+    cap = int(capacity) if capacity is not None else max_count
+    if not max_count:
+        cap = cap or 1
+    return _Layout(counts_np, np.arange(m, dtype=np.int64) * cap, m * cap,
+                   cap, False)
+
+
+def _empty_layout(flat_columns: Columns, lay: _Layout) -> Columns:
+    """Zero-filled columns of an empty layout, device-backed where the
+    dtype round-trips."""
+    out: Columns = {}
+    for k, v in flat_columns.items():
+        v = np.asarray(v)
+        if dtype_roundtrips(v.dtype):
+            out[k] = jnp.zeros(lay.shape(v.shape[1:]), v.dtype)
+        else:
+            out[k] = np.zeros(lay.shape(v.shape[1:]), v.dtype)
+    return out
+
+
+def _assemble(lay: _Layout, packs: List[_Pack], outs,
+              host_cols: List[Tuple[str, Any]],
+              flat_dest_np: Optional[np.ndarray]) -> Columns:
+    """The plan's flat (R, C) packs cut down to the layout's columns, and
+    the host-only columns placed at ``flat_dest_np`` on the host."""
+    columns: Columns = {}
+    total = lay.total
+    for p, mat in zip(packs, outs):
+        # eager slice from the row bucket down to the real layout
+        if lay.bucketed:
+            flat = mat[:total]
+            for name, trail, c0, c1 in p.members:
+                columns[name] = flat[:, c0:c1].reshape((total,) + trail)
+        else:
+            grid = mat[:total].reshape(lay.m, lay.cap, p.width)
+            for name, trail, c0, c1 in p.members:
+                columns[name] = grid[:, :, c0:c1].reshape(
+                    (lay.m, lay.cap) + trail)
+    for name, v in host_cols:
+        buf = np.zeros((total + 1,) + v.shape[1:], v.dtype)
+        buf[flat_dest_np] = v
+        columns[name] = buf[:total].reshape(lay.shape(v.shape[1:]))
+    return columns
+
+
 def device_scatter_padded(flat_columns: Columns, pids, counts, *,
                           capacity: Optional[int] = None,
                           capacity_map: Optional[CapacityMap] = None,
@@ -686,52 +817,18 @@ def device_scatter_padded(flat_columns: Columns, pids, counts, *,
     interpret = _resolve_interpret(interpret)
     use_kernel = _resolve_use_kernel(use_kernel)
     mode = _resolve_mode(mode)
-    counts_np = np.asarray(counts).astype(np.int64)
-    m = int(counts_np.shape[0])
-    n = int(counts_np.sum())
-    max_count = int(counts_np.max()) if n else 0
-    if capacity_map is not None:
-        if capacity is not None:
-            raise ValueError("pass capacity or capacity_map, not both")
-        if capacity_map.num_partitions != m:
-            raise ValueError(
-                f"capacity_map covers {capacity_map.num_partitions} "
-                f"partitions, counts cover {m}")
-        _check_overflow(counts_np, capacity_map.capacities)
-        offsets_np = capacity_map.offsets.astype(np.int64)
-        total = capacity_map.total_slots
-        cap = 0
-    else:
-        if capacity is not None and int(capacity) < max_count:
-            _check_overflow(counts_np,
-                            np.full(m, int(capacity), dtype=np.int64))
-        cap = int(capacity) if capacity is not None else max_count
-        offsets_np = np.arange(m, dtype=np.int64) * cap
-        total = m * cap
-
-    def _shape(trail: Tuple[int, ...]) -> Tuple[int, ...]:
-        if capacity_map is not None:
-            return (total,) + trail
-        return (m, cap) + trail
-
+    lay = _plan_layout(counts, capacity, capacity_map)
+    m, n = lay.m, lay.n
     if n == 0:
-        if capacity_map is None:
-            cap = cap or 1
-        out: Columns = {}
-        for k, v in flat_columns.items():
-            v = np.asarray(v)
-            if dtype_roundtrips(v.dtype):      # stay device-backed
-                out[k] = jnp.zeros(_shape(v.shape[1:]), v.dtype)
-            else:
-                out[k] = np.zeros(_shape(v.shape[1:]), v.dtype)
-        return out
+        return _empty_layout(flat_columns, lay)
 
     dev_cols, host_cols = _split_columns(flat_columns, device_columns)
     B = shape_bucket(n)
-    R = shape_bucket(total)  # output-row bucket: offsets traced, not keyed
+    R = shape_bucket(lay.total)  # output-row bucket: offsets traced, not keyed
 
     with _span("shuffle.dispatch", "shuffle", op="scatter", rows=n, m=m,
-               bucket=B, mode=mode, h2d_bytes=0, d2h_bytes=0):
+               max_worker_rows=int(lay.counts.max()), bucket=B, mode=mode,
+               h2d_bytes=0, d2h_bytes=0):
         if mode == "fused":
             packs = _build_packs(dev_cols, n, B)
             if isinstance(pids, jax.Array):
@@ -745,8 +842,8 @@ def device_scatter_padded(flat_columns: Columns, pids, counts, *,
                                        use_kernel)
             plan.calls += 1
             flat_dest_d, outs = plan.fn(
-                pids_p, upload(counts_np.astype(np.int32)), jnp.int32(n),
-                upload(offsets_np.astype(np.int32)),
+                pids_p, upload(lay.counts.astype(np.int32)), jnp.int32(n),
+                upload(lay.offsets.astype(np.int32)),
                 tuple(upload(p.data) for p in packs))
             flat_dest_np = None
             if host_cols:
@@ -756,30 +853,56 @@ def device_scatter_padded(flat_columns: Columns, pids, counts, *,
             # source every empty (worker, slot) cell gathers from
             packs = _build_packs(dev_cols, n, B + 1)
             pids_np = fetch(pids).astype(np.int64)
-            flat_dest_np = host_counting_sort_dest(pids_np, counts_np, cap,
-                                                   dest_offsets=offsets_np)
+            flat_dest_np = host_counting_sort_dest(
+                pids_np, lay.counts, lay.cap, dest_offsets=lay.offsets)
             inv = np.full(R, B, np.int32)
             inv[flat_dest_np] = np.arange(n, dtype=np.int32)
             plan = _hostperm_scatter_plan(m, B, R, _pack_spec(packs))
             plan.calls += 1
             outs = plan.fn(upload(inv), tuple(upload(p.data) for p in packs))
+    return _assemble(lay, packs, outs, host_cols, flat_dest_np)
 
-    columns: Columns = {}
-    for p, mat in zip(packs, outs):
-        # eager slice from the row bucket down to the real layout
-        if capacity_map is not None:
-            flat = mat[:total]
-            for name, trail, c0, c1 in p.members:
-                columns[name] = flat[:, c0:c1].reshape((total,) + trail)
-        else:
-            grid = mat[:total].reshape(m, cap, p.width)
-            for name, trail, c0, c1 in p.members:
-                columns[name] = grid[:, :, c0:c1].reshape((m, cap) + trail)
-    for name, v in host_cols:
-        buf = np.zeros((total + 1,) + v.shape[1:], v.dtype)
-        buf[flat_dest_np] = v
-        columns[name] = buf[:total].reshape(_shape(v.shape[1:]))
-    return columns
+
+def device_place_presorted(flat_columns: Columns, counts, *,
+                           capacity: Optional[int] = None,
+                           capacity_map: Optional[CapacityMap] = None,
+                           mode: Optional[str] = None,
+                           device_columns: Optional[Columns] = None
+                           ) -> Columns:
+    """Rows already segmented per worker by ``counts`` (worker 0's rows
+    first) → the persistent padded layout, as :func:`device_scatter_padded`
+    lays it out and returns it.  Each worker's rows keep their order, so
+    the slot of every row is known without a sort: one cached gather per
+    dtype pack (``fused``), or a host placement and the same gather
+    (``hostperm``).  No kernel runs and no rows change worker, so this is
+    no shuffle dispatch: its transfers count on the caller's span."""
+    mode = _resolve_mode(mode)
+    lay = _plan_layout(counts, capacity, capacity_map)
+    n = lay.n
+    if n == 0:
+        return _empty_layout(flat_columns, lay)
+    dev_cols, host_cols = _split_columns(flat_columns, device_columns)
+    B = shape_bucket(n)
+    R = shape_bucket(lay.total)
+    flat_dest_np = None
+    if mode == "fused":
+        packs = _build_packs(dev_cols, n, B)
+        plan = _fused_place_plan(lay.m, B, R, _pack_spec(packs))
+        plan.calls += 1
+        outs = plan.fn(upload(lay.counts.astype(np.int32)),
+                       upload(lay.offsets.astype(np.int32)),
+                       tuple(upload(p.data) for p in packs))
+    else:
+        packs = _build_packs(dev_cols, n, B + 1)
+        flat_dest_np = host_presorted_dest(lay.counts, lay.cap, lay.offsets)
+        inv = np.full(R, B, np.int32)
+        inv[flat_dest_np] = np.arange(n, dtype=np.int32)
+        plan = _hostperm_scatter_plan(lay.m, B, R, _pack_spec(packs))
+        plan.calls += 1
+        outs = plan.fn(upload(inv), tuple(upload(p.data) for p in packs))
+    if host_cols and flat_dest_np is None:
+        flat_dest_np = host_presorted_dest(lay.counts, lay.cap, lay.offsets)
+    return _assemble(lay, packs, outs, host_cols, flat_dest_np)
 
 
 # ---------------------------------------------------------------------------

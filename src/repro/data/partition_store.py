@@ -44,10 +44,11 @@ from ..core.partitioner import (HASH, PartitionerCandidate, RANDOM,
                                 ROUND_ROBIN)
 from ..obs.tracer import span as _span
 from .capacity import CapacityMap, plan_capacity_map, valid_slot_index
-from .device_repartition import (device_repartition_dataset,
+from .device_repartition import (device_place_presorted,
+                                 device_repartition_dataset,
                                  device_scatter_padded, dtype_roundtrips,
                                  flatten_dataset, host_counting_sort_dest,
-                                 shuffle_pids)
+                                 host_presorted_dest, shuffle_pids)
 from .transfer import fetch
 
 
@@ -72,20 +73,6 @@ class RetiredGenerationError(KeyError):
 # the per-worker Python copy loop (lives in device_repartition so the
 # hostperm shuffle plans share the exact same placement)
 _counting_sort_dest = host_counting_sort_dest
-
-
-def _presorted_dest(counts: np.ndarray, cap: int,
-                    dest_offsets: Optional[np.ndarray] = None) -> np.ndarray:
-    """Same placement for rows already segmented per worker (write_layout):
-    no sort needed, the worker id is implied by the segmentation.  A
-    bucketed layout passes its per-partition ``dest_offsets``."""
-    m = counts.shape[0]
-    pids = np.repeat(np.arange(m, dtype=np.int64), counts)
-    offsets = np.concatenate([[0], np.cumsum(counts)[:-1]])
-    rank = np.arange(pids.shape[0], dtype=np.int64) - offsets[pids]
-    if dest_offsets is None:
-        return pids * cap + rank
-    return np.asarray(dest_offsets, dtype=np.int64)[pids] + rank
 
 
 @dataclass
@@ -164,6 +151,12 @@ class StoredDataset:
         if slots <= 0:
             return 0
         return int(self.padded_bytes * (self.num_rows / slots))
+
+    @property
+    def device_bytes(self) -> int:
+        """Bytes of the columns held in device memory."""
+        return int(sum(int(v.nbytes) for v in self.columns.values()
+                       if isinstance(v, jax.Array)))
 
     def padding_waste(self) -> int:
         """Bytes spent on padding alone — what skew costs this layout."""
@@ -869,18 +862,37 @@ class PartitionStore:
         partition nodes (e.g. iterative PageRank writing updated ranks).
 
         ``device_columns`` — device-resident flats from an upstream device
-        shuffle (engine d2d chain); the device scatter consumes them in
-        place of re-uploading the matching host columns."""
+        shuffle (engine d2d chain); the device placement consumes them in
+        place of re-uploading the matching host columns.
+
+        Spanned as ``store.write_layout``: the rows, the new generation,
+        the generations retained (``retired``) and the device bytes the
+        live and retained generations hold (``held_bytes``)."""
         counts = np.asarray(counts, np.int64)
         n = int(counts.sum())
-        cmap = self._plan_cmap(counts)
-        columns = self._materialize_layout(flat_columns, counts, cmap,
-                                           device_columns=device_columns)
-        nbytes = int(sum(np.asarray(v).nbytes for v in flat_columns.values()))
-        ds = StoredDataset(name=name, columns=columns, counts=counts,
-                           partitioner=partitioner, num_rows=n, nbytes=nbytes,
-                           capacity_map=cmap)
-        return self._install(name, ds)
+        with _span("store.write_layout", "store", dataset=name, rows=n,
+                   h2d_bytes=0) as sp:
+            cmap = self._plan_cmap(counts)
+            columns = self._materialize_layout(flat_columns, counts, cmap,
+                                               device_columns=device_columns)
+            nbytes = int(sum(np.asarray(v).nbytes
+                             for v in flat_columns.values()))
+            ds = StoredDataset(name=name, columns=columns, counts=counts,
+                               partitioner=partitioner, num_rows=n,
+                               nbytes=nbytes, capacity_map=cmap)
+            ds = self._install(name, ds)
+            if sp.recording:
+                retired = self.retired_generations(name)
+                sp.set(generation=ds.generation, retired=len(retired),
+                       held_bytes=sum(d.device_bytes
+                                      for d in [ds] + retired))
+        return ds
+
+    def retired_generations(self, name: str) -> List[StoredDataset]:
+        """The superseded generations of ``name`` the store still holds,
+        oldest first."""
+        with self._swap_lock:
+            return list(self._retired.get(name, ()))
 
     def _materialize_layout(self, flat_columns: Columns, counts: np.ndarray,
                             cmap: Optional[CapacityMap],
@@ -893,14 +905,12 @@ class PartitionStore:
         n = int(counts.sum())
         cap = int(counts.max()) if n else 1
         if self._device_resident:
-            pids = np.repeat(np.arange(self.m, dtype=np.int32), counts)
-            return device_scatter_padded(
-                flat_columns, pids, counts,
+            return device_place_presorted(
+                flat_columns, counts,
                 capacity=None if cmap is not None else cap,
-                capacity_map=cmap, interpret=self.interpret,
-                device_columns=device_columns)
+                capacity_map=cmap, device_columns=device_columns)
         if cmap is not None:
-            dest = _presorted_dest(counts, 0, dest_offsets=cmap.offsets)
+            dest = host_presorted_dest(counts, 0, dest_offsets=cmap.offsets)
             total = cmap.total_slots
             columns: Columns = {}
             for k, v in flat_columns.items():
@@ -909,7 +919,7 @@ class PartitionStore:
                 buf[dest] = v
                 columns[k] = buf
             return columns
-        dest = _presorted_dest(counts, cap)
+        dest = host_presorted_dest(counts, cap)
         columns = {}
         for k, v in flat_columns.items():
             v = np.asarray(v)

@@ -13,8 +13,7 @@ import pytest
 from repro.core import Engine, author_integrator, enumerate_candidates
 from repro.core.engine import TableVal
 from repro.data import device_repartition as dr
-from repro.data.partition_store import (PartitionStore, _counting_sort_dest,
-                                        _presorted_dest)
+from repro.data.partition_store import PartitionStore, _counting_sort_dest
 from repro.kernels.hash_partition.hash_partition import (hash_partition_padded,
                                                          scatter_perm)
 from repro.kernels.hash_partition.ref import (hash_partition_padded_ref,
@@ -92,7 +91,7 @@ def test_counting_sort_dest_matches_worker_loop():
 def test_presorted_dest_matches_segmented_loop():
     counts = np.array([3, 0, 5, 2], np.int64)
     cap = int(counts.max())
-    dest = _presorted_dest(counts, cap)
+    dest = dr.host_presorted_dest(counts, cap)
     n = int(counts.sum())
     v = np.arange(n, dtype=np.int32)
     buf = np.zeros(4 * cap, np.int32)

@@ -167,6 +167,29 @@ def test_fused_plan_names_its_program_and_kernels(one_chip, kind, module):
     assert calls and set(calls) <= _bench_kernels(), calls
 
 
+def test_place_plan_compiles_for_v5e(one_chip):
+    """The write-back's placement of rows already segmented per worker is
+    one gather per pack under its own program name: no scatter and no
+    kernel, so no shuffle dispatch."""
+    m, i32 = PLAN_WORKERS, jnp.int32
+    packs = tuple(_spec(one_chip, (PLAN_BUCKET, width), jnp.dtype(dt))
+                  for dt, width in PACKS)
+    try:
+        plan = dr._fused_place_plan(m, PLAN_BUCKET, PLAN_BUCKET, PACKS)
+        text = plan.fn.lower(_spec(one_chip, (m,), i32),
+                             _spec(one_chip, (m,), i32),
+                             packs).compile().as_text()
+    finally:
+        dr.clear_plan_cache()
+    assert re.search(r"^HloModule jit_store_place\b", text, re.M)
+    ops = re.findall(r"= (\w+)\[([\d,]*)\]\S* (scatter|gather)\(", text)
+    assert not [op for _dt, _dims, op in ops if op == "scatter"], ops
+    row_gathers = sorted(dt for dt, dims, op in ops
+                         if op == "gather" and "," in dims)
+    assert row_gathers == ["f32", "s32"], ops
+    assert 'custom_call_target="tpu_custom_call"' not in text
+
+
 # -- entry-point guards --------------------------------------------------------
 
 def _load_chip_smoke():
