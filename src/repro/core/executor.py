@@ -47,10 +47,13 @@ class TableVal:
 
     ``device_columns`` is the device-to-device relay (DESIGN §5): flat
     jax-array copies of (a subset of) ``columns`` left on device by a scan
-    of a device-backed dataset or by a device repartition.  Row-preserving
-    nodes pass it through; the next device stage (repartition, store write)
-    consumes it instead of re-uploading the host columns.  Any row-changing
-    op (join, aggregate, filter, flatten, map) drops it."""
+    of a device-backed dataset or by a device repartition.  An elided
+    partition, an ``apply`` without a function and a write pass it
+    through; the next device stage (repartition, store write) consumes it
+    instead of re-uploading the host columns.  Every other set op (join,
+    aggregate, filter, flatten, a map with a function) drops it — the
+    rules the planner reads to relay a scan only where a device stage
+    reads it (``Planner._bind_relays``)."""
     columns: Columns
     counts: np.ndarray                       # (m,) rows per worker segment
     partitioner: Optional[Any] = None        # current PartitionerCandidate
@@ -93,6 +96,8 @@ class EngineStats:
     planning_s: float = 0.0          # plan/compile wall for this run (0 on hit)
     plan_cache_hit: Optional[bool] = None   # None when run outside a Session
     candidate_measure_passes: int = 0       # measurement-pass executions
+    # scans whose device relay the plan left out: no device step reads it
+    scan_relays_skipped: int = 0
     # durable-tier I/O this run caused (DESIGN §10): segment bytes written
     # (autoflushed generations) + read (spill rehydration), and the wall
     # spent on them — the Observer feeds these into the cost model's I/O
@@ -211,15 +216,19 @@ class Executor:
                     with _span("scan.fetch", "exec", d2h_bytes=0):
                         flat = ds.gather()
                     dev = None
+                    # bound per scan at plan time: true only where a
+                    # device step reads the relayed columns
                     if step.device_relay:
                         with _span("scan.relay", "exec", h2d_bytes=0) as rsp:
                             dev = device_flat_columns(ds)
                             rsp.set(columns=len(dev or ()))
+                    elif plan.backend.device_relay:
+                        stats.scan_relays_skipped += 1
                     stats.input_bytes += ds.nbytes
                     stats.padded_bytes += int(ds.padded_bytes)
                     stats.valid_bytes += int(ds.valid_bytes)
                     ssp.set(dataset=step.dataset, generation=ds.generation,
-                            rows=ds.num_rows)
+                            rows=ds.num_rows, relay=step.device_relay)
                     vals[step.nid] = TableVal(flat, ds.counts.copy(),
                                               ds.partitioner,
                                               device_columns=dev)
@@ -295,7 +304,7 @@ class Executor:
         statically against the pinned store layout); only the key
         evaluation, the measurement pass (when observed) and the actual
         data movement happen here."""
-        table: TableVal = _first_table(vals, g, step.nid)
+        table: TableVal = vals[g.first_set_ancestor(step.nid)]
         key_vals = np.asarray(vals[step.key_node]).reshape(-1)
 
         # observation (DESIGN §8): per-candidate runtime stats measured at
@@ -431,8 +440,8 @@ class Executor:
             if v.ndim >= 2:
                 fan = v.shape[1]
                 cols[k] = v.reshape((-1,) + v.shape[2:])
-        if fan is None:
-            return table
+        if fan is None:          # nothing to flatten; the relay stops here
+            return TableVal(table.columns, table.counts, table.partitioner)
         for k, v in table.columns.items():
             if v.ndim == 1:
                 cols[k] = np.repeat(v, fan)
@@ -508,13 +517,3 @@ def _record_candidate_stats(out: Dict[str, Dict[str, float]], sig: str,
     for k, v in st.items():
         cur[k] = min(cur[k], v) if k == "distinct_keys" else max(cur[k], v)
 
-
-def _first_table(vals, g, nid):
-    for p in g.parents(nid):
-        v = vals.get(p)
-        if isinstance(v, TableVal):
-            return v
-        sub = _first_table(vals, g, p)
-        if sub is not None:
-            return sub
-    return None

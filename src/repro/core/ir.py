@@ -197,6 +197,20 @@ class IRGraph:
         return [nid for nid in self.scans
                 if self.nodes[nid].params.get("dataset") == dataset]
 
+    def first_set_ancestor(self, nid: int) -> Optional[int]:
+        """The first set-valued node (a ``SET_OPS`` kind) that a depth-first
+        walk over ``nid``'s parents, in order, meets: lambda nodes (attr,
+        parse, ...) hold no set and are walked through.  This is the table
+        a partition node re-buckets, for the executor and the planner
+        alike."""
+        for p in self.parents(nid):
+            if self.nodes[p].kind in SET_OPS:
+                return p
+            sub = self.first_set_ancestor(p)
+            if sub is not None:
+                return sub
+        return None
+
     # -- structure -----------------------------------------------------------
     def toposort(self, within: Optional[Set[int]] = None) -> List[int]:
         ids = set(self.nodes) if within is None else set(within)

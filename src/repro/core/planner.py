@@ -185,7 +185,8 @@ class PhysicalPlan:
         for s in self.steps:
             if s.kind == "scan":
                 lines.append(f"    [{s.nid:3d}] scan {s.dataset} "
-                             f"rows={s.rows} gen={s.generation}")
+                             f"rows={s.rows} gen={s.generation}"
+                             + (" relay" if s.device_relay else ""))
             elif s.kind == "partition":
                 head = (f"    [{s.nid:3d}] partition[{s.strategy}] "
                         f"key<-n{s.key_node}")
@@ -205,6 +206,37 @@ class PhysicalPlan:
         lines.append(f"  shuffles: elided={len(self.elided)} "
                      f"performed={len(self.shuffled)}")
         return "\n".join(lines)
+
+
+def _bind_relays(g: IRGraph, steps: List[PlanStep]) -> None:
+    """Set ``device_relay`` on exactly the scans whose relayed device
+    columns (DESIGN §5) some device step reads.
+
+    Follows the executor's rules over ``steps`` in topological order: a
+    scan starts a relay; an elided partition, an ``apply`` without a
+    function and a write carry their input's; a shuffled device partition
+    (of the table ``first_set_ancestor`` names) and a write read it; every
+    other set op drops it.  A scan nothing reads relays nothing."""
+    # set node → the scan whose relay it holds (None: it holds none)
+    carried: Dict[int, Optional[int]] = {}
+    read = set()
+    for s in steps:
+        if s.kind == "scan":
+            carried[s.nid] = s.nid
+        elif s.kind == "partition":
+            src = carried.get(g.first_set_ancestor(s.nid))
+            if s.elide:
+                carried[s.nid] = src
+            elif s.device_op:
+                read.add(src)
+        elif s.kind == "apply" and g.nodes[s.nid].params.get("fn") is None:
+            carried[s.nid] = carried.get(g.parents(s.nid)[0])
+        elif s.kind == "write":
+            carried[s.nid] = src = carried.get(g.parents(s.nid)[0])
+            read.add(src)
+    for s in steps:
+        if s.kind == "scan":
+            s.device_relay = s.nid in read
 
 
 # ---------------------------------------------------------------------------
@@ -368,7 +400,6 @@ class Planner:
                 if ds is not None:
                     step.generation = ds.generation
                     step.rows = ds.num_rows
-                step.device_relay = backend.device_relay
             elif kind == "partition":
                 step.key_node = g.parents(nid)[0]
                 step.strategy = node.params.get("strategy", "hash")
@@ -403,6 +434,8 @@ class Planner:
             elif kind == "write":
                 step.dataset = node.params["dataset"]
             steps.append(step)
+        if backend.device_relay:
+            _bind_relays(g, steps)
         return PhysicalPlan(key=key, workload=logical.workload,
                             workload_id=logical.workload_id, graph=g,
                             steps=tuple(steps), backend=backend,
