@@ -143,7 +143,7 @@ def bench_device_rebucket():
     np.testing.assert_array_equal(host_cols["val"], dev_cols["val"])
     k_cols, k_counts = device_rebucket(
         {k: v[:8192] for k, v in cols.items()}, keys[:8192], m,
-        mode="fused", use_kernel=True, interpret=True)
+        use_kernel=True, interpret=True)
     ok = bool(np.array_equal(
         k_cols["val"],
         device_rebucket({k: v[:8192] for k, v in cols.items()}, keys[:8192],

@@ -109,9 +109,7 @@ def _backend_cases():
 
 
 def repartition_backends(workers: int = 8):
-    import jax
     from repro.configs import lachesis_paper
-    on_tpu = jax.default_backend() == "tpu"
     backends = lachesis_paper.get().engine_backends
     for name, wl, tables in _backend_cases():
         res = {}
@@ -123,13 +121,12 @@ def repartition_backends(workers: int = 8):
                                         backend=backend)
         h, d = res["host"], res["device"]
         assert d["device_repartitions"] == d["shuffles"] > 0
-        mode = "fused kernel plans" if on_tpu else "hostperm plans"
         emit(f"repartition_{name}_device", d["wall_s"] * 1e6,
              f"host={h['wall_s'] * 1e6:.0f}us "
              f"device/host={d['wall_s'] / h['wall_s']:.2f}x "
              f"shuffles={d['shuffles']} "
              f"device_repartitions={d['device_repartitions']} "
-             f"bytes={d['shuffle_bytes']} ({mode})")
+             f"bytes={d['shuffle_bytes']}")
 
 
 # -- single-pass device shuffle (ISSUE 2): argsort vs counting-sort plans ----

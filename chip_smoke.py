@@ -327,13 +327,16 @@ def run_checked(sessions, wl, ref, *, shuffles: int, elided: int,
 
 
 def assert_plan_keys() -> None:
-    """Every ShufflePlan used ran the compiled kernels."""
+    """Every ShufflePlan used ran the compiled kernels: rebucket and
+    scatter keys end in their (interpret, use_kernel) flags; place keys
+    run no kernel."""
     keys = dr.plan_keys()
     assert keys, "no ShufflePlan was built"
     for key in keys:
         log(f"ShufflePlan {key}")
-        mode, interpret, use_kernel = key[-1], key[-3], key[-2]
-        assert (mode, interpret, use_kernel) == ("fused", False, True), key
+        if key[0] != "place":
+            assert key[0] in ("rebucket", "scatter"), key
+            assert tuple(key[-2:]) == (False, True), key
 
 
 # ---------------------------------------------------------------------------

@@ -93,7 +93,7 @@ class Session:
 
     def __init__(self, store: Optional[PartitionStore] = None, *,
                  num_workers: int = 8, backend: str = "host",
-                 matching: bool = True, interpret: Optional[bool] = None,
+                 matching: bool = True,
                  net_bandwidth: float = 1.25e9,
                  history=None, registry: Optional[BackendRegistry] = None,
                  plan_cache_capacity: int = 128,
@@ -129,7 +129,6 @@ class Session:
                                    backend=self._backend.name
                                    if self._backend.device_resident
                                    else "host",
-                                   interpret=interpret,
                                    registry=self.registry,
                                    root=store_path,
                                    memory_budget_bytes=memory_budget_bytes,
@@ -147,7 +146,7 @@ class Session:
                                matching=matching,
                                cache_capacity=plan_cache_capacity,
                                metrics=self.metrics_registry)
-        self.executor = Executor(store, interpret=interpret)
+        self.executor = Executor(store)
         self._current: Optional[Workload] = None
         self._wl_counter = 0
         # last-seen device trace counter, for per-run retrace deltas in
@@ -168,8 +167,8 @@ class Session:
     def store(self):
         return self.planner.store
 
-    # matching/interpret forward into the planner/executor: mutating them
-    # takes effect on the next run (matching is part of the plan-cache key)
+    # matching forwards into the planner: mutating it takes effect on the
+    # next run (matching is part of the plan-cache key)
     @property
     def matching(self) -> bool:
         return self.planner.matching
@@ -177,14 +176,6 @@ class Session:
     @matching.setter
     def matching(self, v: bool) -> None:
         self.planner.matching = bool(v)
-
-    @property
-    def interpret(self) -> Optional[bool]:
-        return self.executor.interpret
-
-    @interpret.setter
-    def interpret(self, v: Optional[bool]) -> None:
-        self.executor.interpret = v
 
     @property
     def num_workers(self) -> int:

@@ -9,7 +9,6 @@
 #   backends         — capability-queried backend registry (DESIGN §9)
 #   planner          — Workload → LogicalPlan → PhysicalPlan + plan cache
 #   executor         — runs frozen PhysicalPlans (§4 semantics)
-#   engine           — legacy eager facade, now a deprecation shim
 #   drl              — actor-critic selector + trace simulator (§3.1.3, §4.3)
 #   sharding_bridge  — partitionings ⇄ JAX NamedShardings (TPU adaptation)
 
@@ -27,5 +26,4 @@ from .advisor import (partitioning_creation, apply_decision,
 from .backends import (Backend, BackendRegistry, REGISTRY,
                        UnknownBackendError, resolve_backend)
 from .planner import LogicalPlan, PhysicalPlan, PlanKey, PlanStep, Planner
-from .executor import Executor, StalePlanError
-from .engine import Engine, EngineStats, TableVal
+from .executor import EngineStats, Executor, StalePlanError, TableVal

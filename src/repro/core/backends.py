@@ -1,7 +1,7 @@
 """Backend registry — capability-queried execution backends (DESIGN §9).
 
 Replaces the stringly-typed ``backend="host"/"device"`` flags that every
-entry point (Engine, PartitionStore, Session, benchmarks) used to validate
+entry point (PartitionStore, Session, benchmarks) used to validate
 independently — and that, when misspelled, either surfaced as a bare
 ``KeyError`` or silently fell through to the host path.  All lookups now
 go through one :class:`BackendRegistry`; an unregistered name raises
@@ -63,14 +63,9 @@ class Backend:
     description: str = ""
 
     def partition_op(self, strategy: str) -> str:
-        """The concrete op a partition node binds to under this backend.
-
-        The ShufflePlan mode (fused kernels on TPU, hostperm off-TPU) is
-        resolved lazily at plan time so one registry serves both platforms.
-        """
+        """The concrete op a partition node binds to under this backend."""
         if self.kernel_shuffle and strategy == "hash":
-            from ..data.device_repartition import default_mode
-            return f"device_rebucket[{default_mode()}]"
+            return "device_rebucket"
         if strategy == "range":
             return "host_range"
         return "host_argsort"

@@ -5,8 +5,8 @@ The second half of the planner/executor split (DESIGN §9).  All per-node
 decided at plan time; the executor is a thin loop over the plan's bound
 steps that only carries values, measures stats, and fires observation
 hooks.  Node semantics (columnar numpy execution, the worker-local join
-restriction, the device-to-device relay) are unchanged from the legacy
-``Engine.run`` interpreter and remain bit-identical to it.
+restriction, the device-to-device relay) are bit-identical between the
+host and device backends.
 
 The per-candidate measurement pass (selectivity / distinct keys at every
 partition node — an ``np.unique`` over the key column) is **gated** behind
@@ -36,8 +36,8 @@ Columns = Dict[str, np.ndarray]
 class StalePlanError(RuntimeError):
     """A PhysicalPlan was executed against a store whose layout generation
     no longer matches the one the plan was compiled (and its shuffles were
-    statically elided) against.  Re-plan — ``Session.run`` and the Engine
-    shim do this automatically (:func:`plan_and_execute`); only direct
+    statically elided) against.  Re-plan — ``Session.run`` does this
+    automatically (:func:`plan_and_execute`); only direct
     ``Executor.execute`` calls see this error."""
 
 
@@ -126,13 +126,12 @@ class EngineStats:
 
 class Executor:
     """Executes PhysicalPlans over a :class:`~repro.data.partition_store.
-    PartitionStore`.  Stateless apart from the store/interpret bindings:
-    all run-to-run variation lives in the plan (structure) and the store
+    PartitionStore`.  Stateless apart from the store binding: all
+    run-to-run variation lives in the plan (structure) and the store
     (data)."""
 
-    def __init__(self, store, *, interpret: Optional[bool] = None):
+    def __init__(self, store):
         self.store = store
-        self.interpret = interpret   # None → auto (interpret mode off-TPU)
 
     # ------------------------------------------------------------- execute --
     def execute(self, plan, *, history=None, hooks: Tuple[Callable, ...] = (),
@@ -331,7 +330,6 @@ class Executor:
             # counting-sort permutation + packed gather; upstream device
             # flats (scan of a device store) feed it without re-upload
             res = device_rebucket_full(table.columns, key_vals, table.m,
-                                       interpret=self.interpret,
                                        device_columns=table.device_columns)
             stats.shuffles_performed += 1
             stats.device_repartitions += 1
@@ -460,7 +458,7 @@ def plan_and_execute(planner, executor: Executor, workload, backend, *,
                      history=None, hooks: Tuple[Callable, ...] = (),
                      timestamp: Optional[float] = None,
                      max_replans: int = 4):
-    """The shared run path behind ``Session.run`` and the Engine shim:
+    """The run path behind ``Session.run``:
     plan (cached) + execute, transparently re-planning when a concurrent
     layout swap (e.g. a background Autopilot repartition) lands between
     the cache lookup and the executor's up-front generation check.

@@ -89,7 +89,7 @@ def resolve_fn(label: str, params: Dict[str, Any]) -> Optional[Callable]:
     if kind == "opaque":
         return params.get("fn")
     # set-based ops (apply/join/aggregate/filter/flatten) are executed by the
-    # engine (repro.core.engine), not by subgraph compilation.
+    # executor (repro.core.executor), not by subgraph compilation.
     return params.get("fn")
 
 

@@ -15,8 +15,8 @@ off at execution time.  Planning happens in two stages:
     and the *current* store layout into a frozen :class:`PhysicalPlan`:
     every partition node gets an elide-vs-shuffle decision (Alg. 4 run
     **statically at plan time** against the pinned layout generation), a
-    concrete backend op (``device_rebucket[fused|hostperm]`` /
-    ``host_argsort`` / ``host_range``) and — where the input cardinality
+    concrete backend op (``device_rebucket`` / ``host_argsort`` /
+    ``host_range``) and — where the input cardinality
     is statically known — the ShufflePlan shape bucket the device path
     will dispatch through.
 

@@ -29,22 +29,14 @@ and one host sync, not K.
 counting-sort position: per-partition base offsets from an exclusive prefix
 sum over the histogram plus a running stable rank — an O(N) placement
 replacing the O(N log N) ``jnp.argsort`` + per-column eager gather the old
-path paid.  Two executions of the same math, picked per backend
-(``mode``):
+path paid.  Everything runs inside one jit: the ``hash_partition_padded``
+kernel emits pids with padding routed to an overflow partition ``m``,
+``scatter_perm`` computes the permutation (a stable rank on the MXU over
+prefix-summed base offsets), and the packed gather rides the same trace —
+one device dispatch per shuffle.  Off a TPU the same plans run with the
+jitted jnp oracle of each kernel (``default_use_kernel``).
 
-* ``"fused"`` (TPU default) — everything inside one jit: the
-  ``hash_partition_padded`` kernel emits pids with padding routed to an
-  overflow partition ``m``, ``scatter_perm`` computes the permutation (a
-  stable rank on the MXU over prefix-summed base offsets), and the packed
-  gather/scatter rides the same
-  trace.  One device dispatch per shuffle.
-* ``"hostperm"`` (CPU default) — XLA-on-CPU sorts/scatters are an order of
-  magnitude slower than numpy, so the permutation is computed host-side
-  (numpy radix sort over small-int pids: O(N)) and only the packed
-  gather — the part XLA-CPU is actually good at — stays jitted.  Plans are
-  still cached and traced exactly once per bucket.
-
-Bit-identical guarantee: both modes apply the same Wang hash as
+Bit-identical guarantee: the plans apply the same Wang hash as
 ``core.ir._mix_hash`` and reproduce the stable-sort order exactly — no
 arithmetic touches the payload — so device results match the host numpy
 path bit-for-bit (asserted by the kernel, plan, and property tests).  With
@@ -59,7 +51,6 @@ import threading
 
 from collections import OrderedDict
 from dataclasses import dataclass
-from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -73,8 +64,6 @@ from .capacity import CapacityMap, bucket_capacity, valid_slot_index
 from .transfer import fetch, fetch_tree, upload
 
 Columns = Dict[str, Any]
-
-MODES = ("fused", "hostperm")
 
 
 def default_interpret() -> bool:
@@ -98,14 +87,9 @@ def _resolve_use_kernel(use_kernel: Optional[bool]) -> bool:
 
 
 def default_mode() -> str:
-    return "fused" if jax.default_backend() == "tpu" else "hostperm"
-
-
-def _resolve_mode(mode: Optional[str]) -> str:
-    mode = default_mode() if mode is None else mode
-    if mode not in MODES:
-        raise ValueError(f"mode must be one of {MODES}")
-    return mode
+    """The one ShufflePlan family.  Kept only because the benchmark's
+    start-up line prints it; every platform runs the fused plans."""
+    return "fused"
 
 
 def dtype_roundtrips(dtype) -> bool:
@@ -140,38 +124,6 @@ def as_kernel_keys(keys) -> jax.Array:
     return upload(k.astype(np.int32))
 
 
-def _host_kernel_keys(keys) -> np.ndarray:
-    """Host-side twin of :func:`as_kernel_keys` (int32, same truncation)."""
-    k = np.asarray(keys).reshape(-1)
-    if np.issubdtype(k.dtype, np.integer) or k.dtype == np.bool_:
-        return k.astype(np.int32)
-    if k.dtype == np.float64:
-        k = k.astype(np.float32)
-    if k.dtype == np.float32:
-        return k.view(np.int32)
-    return k.astype(np.int32)
-
-
-def _host_wang(x: np.ndarray) -> np.ndarray:
-    """Numpy twin of ref.wang_hash — identical uint32 arithmetic."""
-    with np.errstate(over="ignore"):
-        x = x.astype(np.uint32)
-        x = (x ^ np.uint32(61)) ^ (x >> np.uint32(16))
-        x = x * np.uint32(9)
-        x = x ^ (x >> np.uint32(4))
-        x = x * np.uint32(0x27D4EB2D)
-        x = x ^ (x >> np.uint32(15))
-    return x
-
-
-@partial(jax.jit, static_argnames=("num_partitions",))
-def _hash_pids_jit(keys, num_partitions: int) -> jax.Array:
-    """Elementwise hash → pid, no histogram (the histogram is cheaper on
-    the host when the permutation is computed there anyway)."""
-    from ..kernels.hash_partition.ref import wang_hash
-    return (wang_hash(keys) % jnp.uint32(num_partitions)).astype(jnp.int32)
-
-
 def device_partition_ids(keys, num_partitions: int, *,
                          interpret: Optional[bool] = None,
                          use_kernel: Optional[bool] = None
@@ -188,33 +140,14 @@ def device_partition_ids(keys, num_partitions: int, *,
 
 def shuffle_pids(keys, num_partitions: int, *,
                  interpret: Optional[bool] = None,
-                 use_kernel: Optional[bool] = None,
-                 mode: Optional[str] = None
-                 ) -> Tuple[Any, np.ndarray]:
-    """Mode-matched pid computation: ``(pids, counts (m,) np.int64)``.
-
-    fused → kernel/oracle hash+histogram on device (pids stay device);
-    hostperm → device keys hash through a tiny jitted elementwise pass, host
-    keys hash with the numpy Wang twin; histogram via np.bincount.
-    """
-    mode = _resolve_mode(mode)
-    if mode == "fused":
-        pids, hist = device_partition_ids(keys, num_partitions,
-                                          interpret=interpret,
-                                          use_kernel=use_kernel)
-        return pids, fetch(hist).astype(np.int64)
-    if isinstance(keys, jax.Array):
-        # bucket the key length so the elementwise jit never retraces per N
-        k = as_kernel_keys(keys)
-        n = int(k.shape[0])
-        B = shape_bucket(n)
-        k_p = k if n == B else jnp.zeros(B, jnp.int32).at[:n].set(k)
-        pids = fetch(_hash_pids_jit(k_p, num_partitions))[:n]
-    else:
-        pids = (_host_wang(_host_kernel_keys(keys))
-                % np.uint32(num_partitions)).astype(np.int32)
-    counts = np.bincount(pids, minlength=num_partitions).astype(np.int64)
-    return pids, counts
+                 use_kernel: Optional[bool] = None
+                 ) -> Tuple[jax.Array, np.ndarray]:
+    """Kernel/oracle hash + histogram on the device: ``(pids, counts (m,)
+    np.int64)``; the pids stay on the device, the histogram is fetched."""
+    pids, hist = device_partition_ids(keys, num_partitions,
+                                      interpret=interpret,
+                                      use_kernel=use_kernel)
+    return pids, fetch(hist).astype(np.int64)
 
 
 # ---------------------------------------------------------------------------
@@ -342,7 +275,7 @@ def _pack_spec(packs: List[_Pack]) -> Tuple[Tuple[str, int], ...]:
 @dataclass
 class ShufflePlan:
     """One compiled dispatch plan, keyed on
-    (kind, shape-bucket, dtype-set, m, capacity, mode)."""
+    (kind, m, shape-bucket, [row-bucket,] dtype-set[, kernel flags])."""
     key: Tuple
     fn: Callable = None
     traces: int = 0          # bumped inside the traced body — retrace counter
@@ -448,7 +381,7 @@ def _fused_rebucket_plan(m: int, B: int, spec: Tuple, interpret: bool,
     """keys + dynamic n + packs → (order, counts, gathered packs), one jit:
     hash kernel (padding → overflow partition m) → counting-sort kernel →
     permutation inversion → packed gather."""
-    key = ("rebucket", m, B, spec, interpret, use_kernel, "fused")
+    key = ("rebucket", m, B, spec, interpret, use_kernel)
 
     def build(plan: ShufflePlan):
         def shuffle_rebucket(keys, n, packs):
@@ -468,36 +401,22 @@ def _fused_rebucket_plan(m: int, B: int, spec: Tuple, interpret: bool,
     return _get_plan(key, build)
 
 
-def _hostperm_rebucket_plan(m: int, B: int, spec: Tuple) -> ShufflePlan:
-    """host-computed counting-sort order + packs → gathered packs (the one
-    stage XLA-on-CPU is fast at stays jitted and retrace-free)."""
-    key = ("rebucket", m, B, spec, "hostperm")
-
-    def build(plan: ShufflePlan):
-        def shuffle_rebucket_hostperm(order, packs):
-            plan.traces += 1
-            return tuple(jnp.take(p, order, axis=0) for p in packs)
-        return shuffle_rebucket_hostperm
-
-    return _get_plan(key, build)
-
-
 def _fused_scatter_plan(m: int, B: int, R: int, spec: Tuple,
                         interpret: bool, use_kernel: bool) -> ShufflePlan:
     """pids + counts + dynamic (n, slot offsets) + packs → flat (R, C) packs.
 
     Counting-sort kernel → each row's slot ``flat_dest`` → one 1-D int32
     scatter that inverts it into ``inv`` (the source row of every slot) →
-    one packed gather per dtype pack, as the rebucket and ``hostperm``
-    plans do: on the TPU a gather of wide rows is far cheaper than a
-    scatter of them.  The per-partition destination base offsets ride
-    along as a traced ``(m,)`` array and the output rows are bucketed to
-    ``R ≥ total slots`` (+1 trash slot), so same-shape writes with
+    one packed gather per dtype pack, as the rebucket plan does: on the
+    TPU a gather of wide rows is far cheaper than a scatter of them.  The
+    per-partition destination base offsets ride along as a traced
+    ``(m,)`` array and the output rows are bucketed to ``R ≥ total
+    slots`` (+1 trash slot), so same-shape writes with
     different key skew — and uniform vs bucketed :class:`CapacityMap`
     layouts alike — reuse one trace; the caller slices ``[:total]`` eagerly
     outside the jit.  The uniform layout simply passes
     ``offsets = arange(m) * cap``."""
-    key = ("scatter", m, B, R, spec, interpret, use_kernel, "fused")
+    key = ("scatter", m, B, R, spec, interpret, use_kernel)
 
     def build(plan: ShufflePlan):
         def store_scatter(pids, counts, n, slot_offs, packs):
@@ -526,24 +445,6 @@ def _fused_scatter_plan(m: int, B: int, R: int, spec: Tuple,
     return _get_plan(key, build)
 
 
-def _hostperm_scatter_plan(m: int, B: int, R: int,
-                           spec: Tuple) -> ShufflePlan:
-    """Gather-formulated padded scatter: ``inv`` maps every (worker, slot)
-    to its source row (B = the all-zeros trash row for empty slots), so the
-    layout materializes as one packed gather — XLA-CPU scatters are slow,
-    its gathers are not.  Output rows are bucketed to ``R ≥ m * cap`` so
-    different capacities share one trace."""
-    key = ("scatter", m, B, R, spec, "hostperm")
-
-    def build(plan: ShufflePlan):
-        def store_scatter_hostperm(inv, packs):
-            plan.traces += 1
-            return tuple(jnp.take(p, inv, axis=0) for p in packs)
-        return store_scatter_hostperm
-
-    return _get_plan(key, build)
-
-
 def _fused_place_plan(m: int, B: int, R: int, spec: Tuple) -> ShufflePlan:
     """counts + slot offsets + packs → flat (R, C) packs, for rows already
     segmented per worker: slot ``r`` of partition ``w`` takes row
@@ -551,7 +452,7 @@ def _fused_place_plan(m: int, B: int, R: int, spec: Tuple) -> ShufflePlan:
     so the layout is one packed gather per dtype, with no counting sort
     and no inversion.  Keyed like the scatter plan: the offsets are
     traced, the output rows bucketed to ``R``."""
-    key = ("place", m, B, R, spec, "fused")
+    key = ("place", m, B, R, spec)
 
     def build(plan: ShufflePlan):
         def store_place(counts, slot_offs, packs):
@@ -586,7 +487,6 @@ class ShuffleResult:
 def device_rebucket_full(columns: Columns, key_vals, num_partitions: int, *,
                          interpret: Optional[bool] = None,
                          use_kernel: Optional[bool] = None,
-                         mode: Optional[str] = None,
                          device_columns: Optional[Columns] = None
                          ) -> ShuffleResult:
     """Re-bucket flat columns by hash(key) % m through one cached plan.
@@ -598,7 +498,6 @@ def device_rebucket_full(columns: Columns, key_vals, num_partitions: int, *,
     """
     interpret = _resolve_interpret(interpret)
     use_kernel = _resolve_use_kernel(use_kernel)
-    mode = _resolve_mode(mode)
     key_arr = key_vals if isinstance(key_vals, jax.Array) \
         else np.asarray(key_vals).reshape(-1)
     n = int(key_arr.shape[0])
@@ -623,32 +522,19 @@ def device_rebucket_full(columns: Columns, key_vals, num_partitions: int, *,
     spec = _pack_spec(packs)
 
     with _span("shuffle.dispatch", "shuffle", op="rebucket", rows=n, m=m,
-               bucket=B, mode=mode, h2d_bytes=0, d2h_bytes=0) as sp:
-        if mode == "fused":
-            keys_p = jnp.zeros(B, jnp.int32).at[:n].set(
-                as_kernel_keys(key_arr))
-            plan = _fused_rebucket_plan(m, B, spec, interpret, use_kernel)
-            plan.calls += 1
-            order_d, counts_d, outs_d = plan.fn(
-                keys_p, jnp.int32(n), tuple(upload(p.data) for p in packs))
-            # one transfer for everything the host needs; its bytes count
-            # on the dispatch
-            with _span("shuffle.fetch", "shuffle"):
-                order_np, counts_np, outs_np = fetch_tree(
-                    (order_d, counts_d, outs_d), sp)
-            order_valid = order_np[:n]
-            counts_np = counts_np.astype(np.int64)
-        else:
-            pids_np, counts_np = shuffle_pids(key_arr, m, mode="hostperm")
-            order_valid = host_counting_order(pids_np)
-            order_p = np.concatenate(
-                [order_valid, np.arange(n, B)]).astype(np.int32)
-            plan = _hostperm_rebucket_plan(m, B, spec)
-            plan.calls += 1
-            outs_d = plan.fn(upload(order_p),
-                             tuple(upload(p.data) for p in packs))
-            with _span("shuffle.fetch", "shuffle"):
-                outs_np = fetch_tree(outs_d, sp)
+               bucket=B, h2d_bytes=0, d2h_bytes=0) as sp:
+        keys_p = jnp.zeros(B, jnp.int32).at[:n].set(as_kernel_keys(key_arr))
+        plan = _fused_rebucket_plan(m, B, spec, interpret, use_kernel)
+        plan.calls += 1
+        order_d, counts_d, outs_d = plan.fn(
+            keys_p, jnp.int32(n), tuple(upload(p.data) for p in packs))
+        # one transfer for everything the host needs; its bytes count on
+        # the dispatch
+        with _span("shuffle.fetch", "shuffle"):
+            order_np, counts_np, outs_np = fetch_tree(
+                (order_d, counts_d, outs_d), sp)
+        order_valid = order_np[:n]
+        counts_np = counts_np.astype(np.int64)
         # the worker that holds most rows, from the fetched histogram
         sp.set(max_worker_rows=int(counts_np.max()))
 
@@ -666,14 +552,12 @@ def device_rebucket_full(columns: Columns, key_vals, num_partitions: int, *,
 
 def device_rebucket(columns: Columns, key_vals, num_partitions: int, *,
                     interpret: Optional[bool] = None,
-                    use_kernel: Optional[bool] = None,
-                    mode: Optional[str] = None
+                    use_kernel: Optional[bool] = None
                     ) -> Tuple[Columns, np.ndarray]:
     """Compatibility wrapper: ``(new_columns incl "__key__", counts)`` —
     the same contract as the engine's host-side shuffle."""
     res = device_rebucket_full(columns, key_vals, num_partitions,
-                               interpret=interpret, use_kernel=use_kernel,
-                               mode=mode)
+                               interpret=interpret, use_kernel=use_kernel)
     return res.columns, res.counts
 
 
@@ -790,7 +674,6 @@ def device_scatter_padded(flat_columns: Columns, pids, counts, *,
                           capacity_map: Optional[CapacityMap] = None,
                           interpret: Optional[bool] = None,
                           use_kernel: Optional[bool] = None,
-                          mode: Optional[str] = None,
                           device_columns: Optional[Columns] = None
                           ) -> Columns:
     """Scatter flat rows into the persistent padded layout.
@@ -816,7 +699,6 @@ def device_scatter_padded(flat_columns: Columns, pids, counts, *,
     """
     interpret = _resolve_interpret(interpret)
     use_kernel = _resolve_use_kernel(use_kernel)
-    mode = _resolve_mode(mode)
     lay = _plan_layout(counts, capacity, capacity_map)
     m, n = lay.m, lay.n
     if n == 0:
@@ -827,56 +709,40 @@ def device_scatter_padded(flat_columns: Columns, pids, counts, *,
     R = shape_bucket(lay.total)  # output-row bucket: offsets traced, not keyed
 
     with _span("shuffle.dispatch", "shuffle", op="scatter", rows=n, m=m,
-               max_worker_rows=int(lay.counts.max()), bucket=B, mode=mode,
+               max_worker_rows=int(lay.counts.max()), bucket=B,
                h2d_bytes=0, d2h_bytes=0):
-        if mode == "fused":
-            packs = _build_packs(dev_cols, n, B)
-            if isinstance(pids, jax.Array):
-                pids_p = jnp.full(B, m, jnp.int32).at[:n].set(
-                    pids.astype(jnp.int32))
-            else:
-                buf = np.full(B, m, np.int32)
-                buf[:n] = np.asarray(pids).astype(np.int32)
-                pids_p = upload(buf)
-            plan = _fused_scatter_plan(m, B, R, _pack_spec(packs), interpret,
-                                       use_kernel)
-            plan.calls += 1
-            flat_dest_d, outs = plan.fn(
-                pids_p, upload(lay.counts.astype(np.int32)), jnp.int32(n),
-                upload(lay.offsets.astype(np.int32)),
-                tuple(upload(p.data) for p in packs))
-            flat_dest_np = None
-            if host_cols:
-                flat_dest_np = fetch(flat_dest_d)[:n]
+        packs = _build_packs(dev_cols, n, B)
+        if isinstance(pids, jax.Array):
+            pids_p = jnp.full(B, m, jnp.int32).at[:n].set(
+                pids.astype(jnp.int32))
         else:
-            # rows [n:B] of each pack are zeros; row B is the explicit trash
-            # source every empty (worker, slot) cell gathers from
-            packs = _build_packs(dev_cols, n, B + 1)
-            pids_np = fetch(pids).astype(np.int64)
-            flat_dest_np = host_counting_sort_dest(
-                pids_np, lay.counts, lay.cap, dest_offsets=lay.offsets)
-            inv = np.full(R, B, np.int32)
-            inv[flat_dest_np] = np.arange(n, dtype=np.int32)
-            plan = _hostperm_scatter_plan(m, B, R, _pack_spec(packs))
-            plan.calls += 1
-            outs = plan.fn(upload(inv), tuple(upload(p.data) for p in packs))
+            buf = np.full(B, m, np.int32)
+            buf[:n] = np.asarray(pids).astype(np.int32)
+            pids_p = upload(buf)
+        plan = _fused_scatter_plan(m, B, R, _pack_spec(packs), interpret,
+                                   use_kernel)
+        plan.calls += 1
+        flat_dest_d, outs = plan.fn(
+            pids_p, upload(lay.counts.astype(np.int32)), jnp.int32(n),
+            upload(lay.offsets.astype(np.int32)),
+            tuple(upload(p.data) for p in packs))
+        flat_dest_np = None
+        if host_cols:
+            flat_dest_np = fetch(flat_dest_d)[:n]
     return _assemble(lay, packs, outs, host_cols, flat_dest_np)
 
 
 def device_place_presorted(flat_columns: Columns, counts, *,
                            capacity: Optional[int] = None,
                            capacity_map: Optional[CapacityMap] = None,
-                           mode: Optional[str] = None,
                            device_columns: Optional[Columns] = None
                            ) -> Columns:
     """Rows already segmented per worker by ``counts`` (worker 0's rows
     first) → the persistent padded layout, as :func:`device_scatter_padded`
     lays it out and returns it.  Each worker's rows keep their order, so
     the slot of every row is known without a sort: one cached gather per
-    dtype pack (``fused``), or a host placement and the same gather
-    (``hostperm``).  No kernel runs and no rows change worker, so this is
-    no shuffle dispatch: its transfers count on the caller's span."""
-    mode = _resolve_mode(mode)
+    dtype pack.  No kernel runs and no rows change worker, so this is no
+    shuffle dispatch: its transfers count on the caller's span."""
     lay = _plan_layout(counts, capacity, capacity_map)
     n = lay.n
     if n == 0:
@@ -884,23 +750,14 @@ def device_place_presorted(flat_columns: Columns, counts, *,
     dev_cols, host_cols = _split_columns(flat_columns, device_columns)
     B = shape_bucket(n)
     R = shape_bucket(lay.total)
+    packs = _build_packs(dev_cols, n, B)
+    plan = _fused_place_plan(lay.m, B, R, _pack_spec(packs))
+    plan.calls += 1
+    outs = plan.fn(upload(lay.counts.astype(np.int32)),
+                   upload(lay.offsets.astype(np.int32)),
+                   tuple(upload(p.data) for p in packs))
     flat_dest_np = None
-    if mode == "fused":
-        packs = _build_packs(dev_cols, n, B)
-        plan = _fused_place_plan(lay.m, B, R, _pack_spec(packs))
-        plan.calls += 1
-        outs = plan.fn(upload(lay.counts.astype(np.int32)),
-                       upload(lay.offsets.astype(np.int32)),
-                       tuple(upload(p.data) for p in packs))
-    else:
-        packs = _build_packs(dev_cols, n, B + 1)
-        flat_dest_np = host_presorted_dest(lay.counts, lay.cap, lay.offsets)
-        inv = np.full(R, B, np.int32)
-        inv[flat_dest_np] = np.arange(n, dtype=np.int32)
-        plan = _hostperm_scatter_plan(lay.m, B, R, _pack_spec(packs))
-        plan.calls += 1
-        outs = plan.fn(upload(inv), tuple(upload(p.data) for p in packs))
-    if host_cols and flat_dest_np is None:
+    if host_cols:
         flat_dest_np = host_presorted_dest(lay.counts, lay.cap, lay.offsets)
     return _assemble(lay, packs, outs, host_cols, flat_dest_np)
 
@@ -974,7 +831,6 @@ def device_flat_columns(ds) -> Optional[Columns]:
 def device_repartition_dataset(ds, partitioner, num_partitions: int, *,
                                interpret: Optional[bool] = None,
                                use_kernel: Optional[bool] = None,
-                               mode: Optional[str] = None,
                                plan_capacity: Optional[Callable] = None
                                ) -> Tuple[Columns, np.ndarray,
                                           Optional[CapacityMap]]:
@@ -984,7 +840,7 @@ def device_repartition_dataset(ds, partitioner, num_partitions: int, *,
     Valid rows are gathered on device, the partition key is evaluated with
     the candidate's compiled key projection (jnp — stays on device), and the
     cached plan scatters straight into the new padded layout.  Only the
-    pids/histogram cross to the host (the histogram sizes the capacity).
+    histogram crosses to the host (it sizes the capacity).
     64-bit columns ride the hybrid path as usual.
 
     ``plan_capacity`` (counts → Optional[CapacityMap]) lets the store
@@ -996,9 +852,9 @@ def device_repartition_dataset(ds, partitioner, num_partitions: int, *,
     with _span("store.pids", "store", h2d_bytes=0, d2h_bytes=0):
         keys = partitioner.key_fn()(flat)
         pids, counts = shuffle_pids(keys, num_partitions, interpret=interpret,
-                                    use_kernel=use_kernel, mode=mode)
+                                    use_kernel=use_kernel)
     cmap = plan_capacity(counts) if plan_capacity is not None else None
     columns = device_scatter_padded(flat, pids, counts, capacity_map=cmap,
                                     interpret=interpret,
-                                    use_kernel=use_kernel, mode=mode)
+                                    use_kernel=use_kernel)
     return columns, counts, cmap

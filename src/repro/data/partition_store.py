@@ -70,8 +70,8 @@ class RetiredGenerationError(KeyError):
     generations — the planner — can retry on exactly this condition."""
 
 # one vectorized counting-sort placement shared by all columns, replacing
-# the per-worker Python copy loop (lives in device_repartition so the
-# hostperm shuffle plans share the exact same placement)
+# the per-worker Python copy loop (lives in device_repartition beside the
+# device plans it is the reference of)
 _counting_sort_dest = host_counting_sort_dest
 
 
@@ -234,7 +234,6 @@ class StoredDataset:
 
 class PartitionStore:
     def __init__(self, num_workers: int = 8, backend: str = "host",
-                 interpret: Optional[bool] = None,
                  max_retired_generations: int = 2,
                  registry=None,
                  root: Optional[str] = None,
@@ -254,7 +253,6 @@ class PartitionStore:
         # device_resident=True gets device-resident columns too
         self._device_resident = b.device_resident
         self._storage_prefetch = b.storage_prefetch
-        self.interpret = interpret      # None → auto (interpret off-TPU)
         # skew-adaptive layout (DESIGN §12): opt-in — when on, writes whose
         # histogram is skewed enough get a bucketed CapacityMap layout
         # instead of the uniform worst-case capacity
@@ -840,15 +838,13 @@ class PartitionStore:
             if (partitioner.strategy == HASH and partitioner.graph is not None
                     and getattr(partitioner, "kernel_dispatchable", True)):
                 keys = partitioner.key_fn()(data)
-                pids, counts = shuffle_pids(keys, self.m,
-                                            interpret=self.interpret)
+                pids, counts = shuffle_pids(keys, self.m)
             else:
                 pids = self._host_pids(data, partitioner, n, seed)
                 counts = np.bincount(pids, minlength=self.m).astype(np.int64)
         cmap = self._plan_cmap(counts)
         columns = device_scatter_padded(data, pids, counts,
-                                        capacity_map=cmap,
-                                        interpret=self.interpret)
+                                        capacity_map=cmap)
         return columns, counts, cmap
 
     def write_layout(self, name: str, flat_columns: Columns,
@@ -1051,8 +1047,7 @@ class PartitionStore:
                 and getattr(partitioner, "kernel_dispatchable", True)):
             rsp.set(path="d2d")
             columns, counts, cmap = device_repartition_dataset(
-                ds, partitioner, self.m, interpret=self.interpret,
-                plan_capacity=self._plan_cmap)
+                ds, partitioner, self.m, plan_capacity=self._plan_cmap)
             new = StoredDataset(name=name, columns=columns, counts=counts,
                                 partitioner=partitioner,
                                 num_rows=int(counts.sum()),

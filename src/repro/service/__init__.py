@@ -1,5 +1,5 @@
 # Autopilot: the online storage-optimizer service (DESIGN §8).
-#   observer   — Session/Engine run hook → auto ExecutionRecords + calibration
+#   observer   — Session run hook → auto ExecutionRecords + calibration
 #   cost_model — what-if layout scoring from measured shuffle throughput
 #   optimizer  — the tick()/background decide→apply loop + Autopilot facade
 #   drivers    — deterministic workload-drift scenarios (tests/bench/demo)

@@ -1,12 +1,12 @@
 """Observer — the "observe" third of the Autopilot loop (DESIGN §8).
 
-Attaches to run hooks — of a :class:`~repro.api.Session` or the legacy
-:class:`~repro.core.engine.Engine` shim — and turns every execution into
-durable signal: an :class:`~repro.core.history.
-ExecutionRecord` appended to the :class:`~repro.core.history.HistoryStore`
-(latency, input/output bytes, per-candidate selectivity/distinct-key stats
-measured at each partition node), plus live shuffle-throughput samples fed
-to the :class:`~repro.service.cost_model.WhatIfCostModel` calibration.
+Attaches to the run hooks of a :class:`~repro.api.Session` and turns
+every execution into durable signal: an
+:class:`~repro.core.history.ExecutionRecord` appended to the
+:class:`~repro.core.history.HistoryStore` (latency, input/output bytes,
+per-candidate selectivity/distinct-key stats measured at each partition
+node), plus live shuffle-throughput samples fed to the
+:class:`~repro.service.cost_model.WhatIfCostModel` calibration.
 The measurement pass at partition nodes only runs while an observer (or
 any other hook/history) is attached; unobserved runs skip it.
 
@@ -43,10 +43,11 @@ class LogicalClock:
 
 
 class Observer:
-    """Auto-appends an ExecutionRecord per observed Session/Engine run.
+    """Auto-appends an ExecutionRecord per observed Session run.
 
-    ``attach(engine)`` registers a run hook; from then on every run of that
-    engine is recorded with this observer's clock — no hand-built records.
+    ``attach(session)`` registers a run hook; from then on every run of
+    that session is recorded with this observer's clock — no hand-built
+    records.
     ``max_records`` (optional) auto-compacts the history so the log stays
     bounded under continuous service writes; compaction (a full-log merge
     + JSONL rewrite) only triggers once the log exceeds ``max_records``
@@ -70,8 +71,7 @@ class Observer:
         self.compacted_total = 0
 
     def attach(self, session) -> "Observer":
-        """Register on anything with ``add_run_hook`` (Session or the
-        legacy Engine shim)."""
+        """Register on anything with ``add_run_hook`` (a Session)."""
         session.add_run_hook(self.on_run)
         return self
 

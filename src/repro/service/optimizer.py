@@ -762,7 +762,7 @@ class Autopilot:
 
     Attaches to anything exposing ``.store`` and ``.add_run_hook`` — a
     :class:`~repro.api.Session` (``session.autopilot()`` is the idiomatic
-    spelling) or the legacy Engine shim::
+    spelling)::
 
         sess = Session(store)
         ap = sess.autopilot(clock=LogicalClock())

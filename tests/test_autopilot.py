@@ -1,6 +1,6 @@
 """Autopilot service tests (DESIGN §8): observe → decide → repartition.
 
-Covers the engine's automatic ExecutionRecords, history compaction, the
+Covers the session's automatic ExecutionRecords, history compaction, the
 what-if cost model, generation swap consistency, advisor decision
 application (host + d2d), the deterministic drift scenario via tick(),
 the background thread mode, and the LRU-bounded shuffle-plan cache.
@@ -12,7 +12,8 @@ import numpy as np
 import pytest
 
 import repro.data.device_repartition as dr
-from repro.core import (Engine, GreedySelector, HistoryStore, apply_decision,
+from repro.api import Session
+from repro.core import (GreedySelector, HistoryStore, apply_decision,
                         author_integrator, enumerate_candidates,
                         partitioning_creation)
 from repro.core.dsl import reddit_loader
@@ -40,9 +41,9 @@ def _seed_store(backend="host", **kw):
 def test_engine_run_auto_records_history():
     store = _seed_store(n_lineitem=2000)
     hist = HistoryStore()
-    eng = Engine(store)
+    sess = Session(store)
     wl = q_orderkey()
-    _, stats = eng.run(wl, history=hist, timestamp=42.0)
+    _, stats = sess.run(wl, history=hist, timestamp=42.0)
 
     assert len(hist.records) == 1
     rec = hist.records[0]
@@ -66,21 +67,21 @@ def test_engine_run_auto_records_history():
 def test_engine_constructor_history_and_hooks():
     store = _seed_store(n_lineitem=1000)
     hist = HistoryStore()
-    eng = Engine(store, history=hist)
+    sess = Session(store, history=hist)
     seen = []
-    eng.add_run_hook(lambda wl, stats: seen.append(stats))
-    eng.run(q_orderkey())
+    sess.add_run_hook(lambda wl, stats: seen.append(stats))
+    sess.run(q_orderkey())
     assert len(hist.records) == 1 and len(seen) == 1
     assert seen[0].candidate_stats    # hooks see the measured stats
 
 
 def test_observer_attach_and_auto_compact():
     store = _seed_store(n_lineitem=1000)
-    eng = Engine(store)
+    sess = Session(store)
     obs = Observer(clock=LogicalClock(), max_records=3,
-                   compact_slack=1).attach(eng)
+                   compact_slack=1).attach(sess)
     for _ in range(6):
-        eng.run(q_orderkey())
+        sess.run(q_orderkey())
     assert obs.records_seen == 6
     # bounded: 3 verbatim + at most one aggregate per skeleton (1 here)
     assert len(obs.history.records) <= 4
@@ -268,12 +269,12 @@ def test_apply_decision_end_to_end(backend):
     store.write("authors", auths)
 
     hist = HistoryStore()
-    eng = Engine(store, backend=backend, history=hist)
+    sess = Session(store, backend=backend, history=hist)
     loader = reddit_loader("loader", "raw", "submissions", "json")
     consumer = author_integrator()
     clock = LogicalClock()
-    eng.run(loader, timestamp=clock())
-    vals0, st0 = eng.run(consumer, timestamp=clock())
+    sess.run(loader, timestamp=clock())
+    vals0, st0 = sess.run(consumer, timestamp=clock())
     assert st0.shuffles_performed == 2 and st0.shuffles_elided == 0
 
     # Alg. 3 decision from the auto-recorded history, applied in place
@@ -287,7 +288,7 @@ def test_apply_decision_end_to_end(backend):
         last = store.write_log[-1]
         assert last["name"] == "submissions" and last.get("path") == "d2d"
 
-    vals1, st1 = eng.run(consumer, timestamp=clock())
+    vals1, st1 = sess.run(consumer, timestamp=clock())
     assert st1.shuffles_elided == 1                  # submissions side
     assert st1.shuffles_performed == 1               # authors still shuffles
 
@@ -360,11 +361,12 @@ def test_drift_scenario_device_d2d():
 
 def test_background_thread_mode():
     store = _seed_store(n_lineitem=2000)
-    eng = Engine(store)
-    ap = Autopilot(eng, config=AutopilotConfig(min_runs=2.0, hysteresis=0.5,
-                                               cooldown_ticks=0))
+    sess = Session(store)
+    ap = Autopilot(sess, config=AutopilotConfig(min_runs=2.0,
+                                                hysteresis=0.5,
+                                                cooldown_ticks=0))
     for _ in range(3):
-        eng.run(q_orderkey())
+        sess.run(q_orderkey())
     ap.start(period_s=0.02)
     try:
         deadline = time.time() + 20.0

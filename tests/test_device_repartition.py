@@ -1,8 +1,8 @@
 """Device-resident repartition path (DESIGN §5).
 
-Engine ``backend="device"`` must execute the TPC-H, Reddit, and PageRank
-example workloads through the Pallas hash-partition kernel (interpret mode
-on CPU) **bit-identically** to the host numpy path — same values, dtypes,
+A ``Session`` with ``backend="device"`` must execute the TPC-H, Reddit,
+and PageRank example workloads through the device ShufflePlans (the jnp
+oracle of each kernel on CPU) **bit-identically** to the host numpy path — same values, dtypes,
 and per-worker counts at every set-valued node.  No hypothesis dependency:
 these run even in the bare container.
 """
@@ -10,9 +10,10 @@ these run even in the bare container.
 import numpy as np
 import pytest
 
-from repro.core import (Engine, Workload, author_integrator,
-                        enumerate_candidates, pagerank_iteration)
-from repro.core.engine import TableVal
+from repro.api import Session
+from repro.core import (Workload, author_integrator, enumerate_candidates,
+                        pagerank_iteration)
+from repro.core.executor import TableVal
 from repro.data.device_repartition import (as_kernel_keys, device_rebucket,
                                            device_scatter_padded,
                                            device_partition_ids)
@@ -73,8 +74,7 @@ def _run(wl, tables, backend, workers=8):
     store = PartitionStore(workers)
     for name, data in tables.items():
         store.write(name, data)           # rr ⇒ every repartition is real
-    eng = Engine(store, backend=backend)
-    return eng.run(wl)
+    return Session(store, backend=backend).run(wl)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))

@@ -1,9 +1,10 @@
-"""Engine tests: partition-aware execution, shuffle elision, correctness."""
+"""Execution tests: partition-aware execution, shuffle elision, correctness."""
 
 import numpy as np
 import pytest
 
-from repro.core import (Engine, author_integrator, enumerate_candidates,
+from repro.api import Session
+from repro.core import (author_integrator, enumerate_candidates,
                         pagerank_iteration)
 from repro.data.partition_store import PartitionStore
 
@@ -34,8 +35,7 @@ def _run(store_partitioned: bool):
     else:
         store.write("submissions", subs)
         store.write("authors", auths)
-    eng = Engine(store)
-    vals, stats = eng.run(wl)
+    vals, stats = Session(store).run(wl)
     join_node = max(n for n, nd in wl.graph.nodes.items()
                     if nd.kind == "join")
     return vals[join_node], stats
@@ -81,8 +81,7 @@ def test_pagerank_iteration_correct():
     store = PartitionStore(num_workers=4)
     store.write("pages", pages, enumerate_candidates(wl.graph, "pages")[0])
     store.write("ranks", ranks, enumerate_candidates(wl.graph, "ranks")[0])
-    eng = Engine(store)
-    vals, stats = eng.run(wl)
+    vals, stats = Session(store).run(wl)
     agg_node = max(n_ for n_, nd in wl.graph.nodes.items()
                    if nd.kind == "aggregate")
     out = vals[agg_node]

@@ -68,13 +68,12 @@ def _session(backend, src, dst, n, m=4):
     return sess, wl
 
 
-@pytest.fixture(params=["host", "device", "device-fused"])
+@pytest.fixture(params=["host", "device", "device-kernel"])
 def backend(request, monkeypatch):
-    """The host backend; the device backend in its CPU mode (hostperm);
-    and the device backend's TPU mode (fused plans, Pallas kernels in
-    interpret mode)."""
-    if request.param == "device-fused":
-        monkeypatch.setattr(dr, "default_mode", lambda: "fused")
+    """The host backend; the device backend with the jnp oracle of each
+    kernel (the CPU default); and the device backend with the Pallas
+    kernels, in interpret mode (the chip's kernel path)."""
+    if request.param == "device-kernel":
         monkeypatch.setattr(dr, "default_use_kernel", lambda: True)
         return "device"
     return request.param
@@ -189,11 +188,16 @@ def test_second_scan_of_ranks_is_elided():
 
 # -- the write-back -----------------------------------------------------------
 
-@pytest.mark.parametrize("mode", ["fused", "hostperm"])
+@pytest.mark.parametrize("counts", [[3, 0, 5, 2, 9], [3, 0, 5, 2, 22]],
+                         ids=["padded", "full_bucket"])
 @pytest.mark.parametrize("bucketed", [False, True])
-def test_presorted_placement_matches_host(mode, bucketed):
-    counts = np.array([3, 0, 5, 2, 9], np.int64)
+def test_presorted_placement_matches_host(counts, bucketed):
+    """Rows segmented per worker placed by the fused place plan ≡ the
+    host placement: n inside its shape bucket (padding rows) and n
+    filling it (none)."""
+    counts = np.array(counts, np.int64)
     n = int(counts.sum())
+    assert (dr.shape_bucket(n) == n) == (n == 32)
     rng = np.random.default_rng(2)
     flat = {"a": rng.normal(size=n).astype(np.float32),
             "b": rng.integers(0, 9, (n, 3)).astype(np.int32),
@@ -202,7 +206,7 @@ def test_presorted_placement_matches_host(mode, bucketed):
     cap = int(counts.max())
     got = dr.device_place_presorted(
         flat, counts, capacity=None if bucketed else cap,
-        capacity_map=cmap, mode=mode)
+        capacity_map=cmap)
     offs = cmap.offsets if bucketed else np.arange(5) * cap
     total = cmap.total_slots if bucketed else 5 * cap
     dest = dr.host_presorted_dest(counts, cap, dest_offsets=offs)

@@ -1,26 +1,22 @@
 """Session API tests (DESIGN §9): planner/executor split, plan cache,
-backend registry, explain() golden output, and the legacy Engine shim.
+backend registry and explain() golden output.
 
-Covers the ISSUE 4 acceptance criteria: Session parity with legacy
-``Engine.run`` (bit-identical host/device), pure plan-cache hits on
-re-runs of an unchanged workload (0 new traces), layout-generation flips
-invalidating exactly the affected plans, deterministic ``explain``, the
-``UnknownBackendError`` bugfix (both entry-point spellings), and the
-gated per-candidate measurement pass.
+Covers: fresh sessions agree bit for bit (and host agrees with device),
+pure plan-cache hits on re-runs of an unchanged workload (0 new traces),
+layout-generation flips invalidating exactly the affected plans,
+deterministic ``explain``, the ``UnknownBackendError`` bugfix (every
+entry point), and the gated per-candidate measurement pass.
 """
-
-import warnings
 
 import numpy as np
 import pytest
 
 import lachesis
 from repro.api import RunResult, Session
-from repro.core import (Engine, UnknownBackendError, author_integrator,
+from repro.core import (UnknownBackendError, author_integrator,
                         enumerate_candidates, pagerank_iteration)
 from repro.core.backends import REGISTRY, Backend, BackendRegistry
 from repro.core.executor import StalePlanError, TableVal
-from repro.data.device_repartition import default_mode
 from repro.data.partition_store import PartitionStore
 
 
@@ -63,7 +59,7 @@ def _assert_same_values(va, vb):
                 np.testing.assert_array_equal(x, y)
 
 
-# -- parity with the legacy Engine -------------------------------------------
+# -- parity between fresh sessions -------------------------------------------
 
 @pytest.mark.parametrize("partitioned", [False, True])
 def test_session_parity_with_engine(partitioned):
@@ -72,9 +68,7 @@ def test_session_parity_with_engine(partitioned):
     assert isinstance(res, RunResult)
 
     wl2, store2 = _seeded_store(partitioned)
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        vals, stats = Engine(store2).run(wl2)
+    vals, stats = Session(store2).run(wl2)
     _assert_same_values(res.values, vals)
     assert res.stats.shuffles_performed == stats.shuffles_performed
     assert res.stats.shuffles_elided == stats.shuffles_elided
@@ -95,7 +89,7 @@ def test_session_parity_host_device():
 def test_session_pagerank_matches_engine():
     """A write-back workload (pagerank writes the ranks it scans): every
     run flips the layout generation, so each run re-plans — and results
-    stay identical to the legacy path."""
+    stay identical to a fresh session's."""
     def build():
         n, fanout = 600, 4
         rng = np.random.default_rng(1)
@@ -129,9 +123,7 @@ def test_session_pagerank_matches_engine():
     assert hit is False
 
     wl2, store2 = build()
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        v1, _ = Engine(store2).run(wl2)
+    v1, _ = Session(store2).run(wl2)
     _assert_same_values(r1.values, v1)
 
 
@@ -301,10 +293,9 @@ def test_explain_device_shows_op_and_bucket():
     sess.write("authors", {"author": np.arange(20, dtype=np.int64),
                            "karma": np.ones(20, np.float32)})
     txt = sess.explain(wl)
-    mode = default_mode()
     # per partition node: bound backend op + static ShufflePlan bucket
-    assert f"op=device_rebucket[{mode}] bucket=B128 shuffle" in txt
-    assert f"op=device_rebucket[{mode}] bucket=B32 shuffle" in txt
+    assert "op=device_rebucket bucket=B128 shuffle" in txt
+    assert "op=device_rebucket bucket=B32 shuffle" in txt
     assert txt == sess.explain(wl)              # deterministic
     # and the elided case still renders under the device backend
     wl2, sess2 = _golden_store(backend="device")
@@ -318,7 +309,6 @@ def test_unknown_backend_all_entry_points(bad):
     wl, store = _seeded_store(False)
     for ctor in (lambda: Session(store, backend=bad),
                  lambda: PartitionStore(backend=bad),
-                 lambda: Engine(store, backend=bad),
                  lambda: Session(store).run(wl, backend=bad),
                  lambda: Session(store).plan(wl, backend=bad)):
         with pytest.raises(UnknownBackendError) as ei:
@@ -331,29 +321,18 @@ def test_unknown_backend_all_entry_points(bad):
     assert issubclass(UnknownBackendError, ValueError)
 
 
-def test_engine_run_backend_override_validated():
-    wl, store = _seeded_store(False)
-    eng = Engine(store)
-    with pytest.raises(UnknownBackendError):
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore", DeprecationWarning)
-            eng.run(wl, backend="dvice")
-
-
 def test_matching_toggle_forwards_to_planner():
-    """The pre-split `eng.matching = False` idiom must keep disabling
-    Alg. 4 elision (the knob lives in the Planner now)."""
+    """`sess.matching = False` disables Alg. 4 elision (the knob lives in
+    the Planner), on a live session and on a fresh one alike."""
     wl, store = _seeded_store(True)
     sess = Session(store)
     assert sess.run(wl).stats.shuffles_elided == 2
     sess.matching = False
     st = sess.run(wl).stats
     assert st.shuffles_elided == 0 and st.shuffles_performed == 2
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        eng = Engine(store)
-        eng.matching = False
-        _, est = eng.run(wl)
+    fresh = Session(store)
+    fresh.matching = False
+    _, est = fresh.run(wl)
     assert est.shuffles_elided == 0 and est.shuffles_performed == 2
 
 
@@ -430,8 +409,7 @@ def test_registry_capabilities_and_plugging():
     with pytest.raises(ValueError):
         reg.register(Backend("host"))           # no silent overwrite
     assert "host" in REGISTRY and "device" in REGISTRY
-    assert REGISTRY.get("device").partition_op("hash").startswith(
-        "device_rebucket[")
+    assert REGISTRY.get("device").partition_op("hash") == "device_rebucket"
     assert REGISTRY.get("host").partition_op("hash") == "host_argsort"
     assert REGISTRY.get("host").partition_op("range") == "host_range"
 
@@ -460,20 +438,6 @@ def test_candidate_measurement_gated_behind_observation(monkeypatch):
     assert res2.stats.candidate_measure_passes == 2
     assert res2.stats.candidate_stats           # hooks see measured stats
     assert seen and seen[0] is res2.stats
-
-
-# -- deprecation shim ---------------------------------------------------------
-
-def test_engine_run_warns_deprecation():
-    wl, store = _seeded_store(True)
-    eng = Engine(store)
-    with pytest.warns(DeprecationWarning, match="Session"):
-        vals, stats = eng.run(wl)
-    assert stats.shuffles_elided == 2
-    # the shim shares the same planner stack: second run is a cache hit
-    with pytest.warns(DeprecationWarning):
-        _, stats2 = eng.run(wl)
-    assert stats2.plan_cache_hit is True
 
 
 # -- session DSL passthrough --------------------------------------------------

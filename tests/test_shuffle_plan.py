@@ -10,8 +10,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import Engine, author_integrator, enumerate_candidates
-from repro.core.engine import TableVal
+from repro.api import Session
+from repro.core import author_integrator, enumerate_candidates
+from repro.core.executor import TableVal
 from repro.data import device_repartition as dr
 from repro.data.partition_store import PartitionStore, _counting_sort_dest
 from repro.kernels.hash_partition.hash_partition import (hash_partition_padded,
@@ -174,10 +175,10 @@ def test_rebucket_bit_identical_inside_bucket():
 
 
 @pytest.mark.parametrize("use_kernel", [True, False])
-def test_fused_mode_matches_hostperm(use_kernel):
-    """The TPU-default fused plan (everything in one jit, kernels in
-    interpret mode on CPU) ≡ the CPU-default hostperm plan ≡ the host numpy
-    path — the mode switch must never change a bit."""
+def test_fused_plans_match_numpy_placement(use_kernel):
+    """The fused rebucket and store plans (everything in one jit; the
+    Pallas kernels in interpret mode on CPU, or their jnp oracle) ≡ the
+    host numpy placement, bit for bit."""
     from repro.core.ir import _mix_hash
     rng = np.random.default_rng(6)
     n, m = 700, 9
@@ -185,29 +186,30 @@ def test_fused_mode_matches_hostperm(use_kernel):
             "d": rng.normal(size=n),                     # float64: hybrid
             "i": rng.integers(0, 7, n).astype(np.int32)}
     keys = rng.integers(0, 3_000, n).astype(np.int64)
-    got_f, counts_f = dr.device_rebucket(cols, keys, m, mode="fused",
-                                         interpret=True,
-                                         use_kernel=use_kernel)
-    got_h, counts_h = dr.device_rebucket(cols, keys, m, mode="hostperm")
+    got, counts = dr.device_rebucket(cols, keys, m, interpret=True,
+                                     use_kernel=use_kernel)
     pids = np.asarray(_mix_hash(jnp.asarray(keys))).astype(np.int64) % m
     order = np.argsort(pids, kind="stable")
-    np.testing.assert_array_equal(counts_f, counts_h)
-    np.testing.assert_array_equal(counts_f, np.bincount(pids, minlength=m))
+    np.testing.assert_array_equal(counts, np.bincount(pids, minlength=m))
     for k, v in cols.items():
-        assert got_f[k].dtype == v.dtype and got_h[k].dtype == v.dtype
-        np.testing.assert_array_equal(got_f[k], v[order])
-        np.testing.assert_array_equal(got_h[k], v[order])
+        assert got[k].dtype == v.dtype
+        np.testing.assert_array_equal(got[k], v[order])
 
-    # scatter side: same (m, cap, ...) layout from both modes
-    pids_d, hist = dr.device_partition_ids(keys, m)
+    # scatter side: the uniform (m, cap, ...) layout at cap = counts.max()
+    pids_d, hist = dr.device_partition_ids(keys, m, interpret=True,
+                                           use_kernel=use_kernel)
+    np.testing.assert_array_equal(np.asarray(pids_d), pids)
     counts = np.asarray(hist).astype(np.int64)
-    sc_f = dr.device_scatter_padded(cols, pids_d, counts, mode="fused",
-                                    interpret=True, use_kernel=use_kernel)
-    sc_h = dr.device_scatter_padded(cols, pids_d, counts, mode="hostperm")
-    for k in cols:
-        assert np.asarray(sc_f[k]).dtype == np.asarray(sc_h[k]).dtype
-        np.testing.assert_array_equal(np.asarray(sc_f[k]),
-                                      np.asarray(sc_h[k]), err_msg=k)
+    cap = int(counts.max())
+    sc = dr.device_scatter_padded(cols, pids_d, counts, interpret=True,
+                                  use_kernel=use_kernel)
+    for k, v in cols.items():
+        want = _numpy_padded_layout(v, pids, m,
+                                    np.arange(m, dtype=np.int64) * cap,
+                                    m * cap).reshape((m, cap) + v.shape[1:])
+        got_k = np.asarray(sc[k])
+        assert got_k.dtype == v.dtype, k
+        np.testing.assert_array_equal(got_k, want, err_msg=k)
 
 
 def _numpy_padded_layout(v, pids, m, offsets, total):
@@ -226,9 +228,9 @@ def _numpy_padded_layout(v, pids, m, offsets, total):
 @pytest.mark.parametrize("use_kernel", [True, False])
 @pytest.mark.parametrize("case", ["full_bucket", "capacity_map_empty",
                                   "u8_wide", "f64_hybrid"])
-def test_fused_scatter_layout_matches_hostperm(case, use_kernel):
-    """The fused store plan's gather-built layout ≡ the hostperm layout ≡
-    the numpy placement, bit for bit, with zeros in every empty slot: n filling
+def test_fused_scatter_layout_matches_numpy(case, use_kernel):
+    """The fused store plan's gather-built layout ≡ the numpy placement,
+    bit for bit, with zeros in every empty slot: n filling
     its bucket (no padding row), a bucketed CapacityMap with empty
     partitions, a wide uint8 column, and a float64 column that rides the
     plan's ``flat_dest`` host-side."""
@@ -261,19 +263,16 @@ def test_fused_scatter_layout_matches_hostperm(case, use_kernel):
         cap = int(counts.max()) + 3
         kw["capacity"] = cap
         offsets, total = np.arange(m, dtype=np.int64) * cap, m * cap
-    sc_f = dr.device_scatter_padded(cols, jnp.asarray(pids, jnp.int32),
-                                    counts, mode="fused", interpret=True,
-                                    use_kernel=use_kernel, **kw)
-    sc_h = dr.device_scatter_padded(cols, jnp.asarray(pids, jnp.int32),
-                                    counts, mode="hostperm", **kw)
+    sc = dr.device_scatter_padded(cols, jnp.asarray(pids, jnp.int32),
+                                  counts, interpret=True,
+                                  use_kernel=use_kernel, **kw)
     for k, v in cols.items():
         want = _numpy_padded_layout(v, pids, m, offsets, total)
         if "capacity" in kw:
             want = want.reshape((m, kw["capacity"]) + v.shape[1:])
-        got_f, got_h = np.asarray(sc_f[k]), np.asarray(sc_h[k])
-        assert got_f.dtype == got_h.dtype == v.dtype, k
-        np.testing.assert_array_equal(got_f, want, err_msg=k)
-        np.testing.assert_array_equal(got_h, want, err_msg=k)
+        got = np.asarray(sc[k])
+        assert got.dtype == v.dtype, k
+        np.testing.assert_array_equal(got, want, err_msg=k)
 
 
 def test_chained_rebucket_relays_fresh_key():
@@ -307,17 +306,6 @@ def _stable_order(keys, m):
 
 
 # -- capacity validation ------------------------------------------------------
-
-def test_hash_pids_jit_buckets_device_keys():
-    """Device-resident keys are padded to the shape bucket before the
-    elementwise hash jit, so varying N never retraces it (regression)."""
-    before = dr._hash_pids_jit._cache_size()
-    for n in (900, 950, 1000):                 # same 1024 bucket
-        keys = jnp.asarray(np.arange(n, dtype=np.int32))
-        pids, counts = dr.shuffle_pids(keys, 8, mode="hostperm")
-        assert pids.shape == (n,) and int(counts.sum()) == n
-    assert dr._hash_pids_jit._cache_size() <= before + 1
-
 
 def test_empty_device_write_stays_device_backed():
     """A 0-row write to a device store must still produce a device-backed
@@ -410,7 +398,6 @@ _MESH_PHASE = """
 import sys
 sys.path[:0] = [{root!r}, {src!r}]
 from repro.data import device_repartition as dr
-dr.default_mode = lambda: "fused"
 import chip_smoke as cs
 tables = cs.make_tables(0.002, 3)
 cs.phase_mesh(tables, {{"q17": cs.ref_q17(tables)}})
@@ -471,9 +458,9 @@ def test_engine_device_store_bit_identical_and_relays_device_columns():
     for name, data in tables.items():
         host.write(name, data)
         dev.write(name, data)
-    vals_h, _ = Engine(host, backend="host").run(wl)
+    vals_h, _ = Session(host, backend="host").run(wl)
     wl2, _ = _full_reddit_case()
-    vals_d, stats_d = Engine(dev, backend="device").run(wl2)
+    vals_d, stats_d = Session(dev, backend="device").run(wl2)
     assert stats_d.device_repartitions > 0
     for nid, h in vals_h.items():
         if not isinstance(h, TableVal):
@@ -502,3 +489,25 @@ def _full_reddit_case(n_sub=2500, n_auth=400, seed=0):
     auths = {"author": np.arange(n_auth, dtype=np.int64),
              "karma": rng.normal(size=n_auth).astype(np.float32)}
     return author_integrator(), {"submissions": subs, "authors": auths}
+
+
+def test_device_session_builds_only_fused_plan_keys():
+    """A device-backend run off the TPU builds the same plan family the
+    chip runs: every live plan key is a fused rebucket or scatter key
+    (ending in its interpret/use_kernel flags) or a place key (ending in
+    its pack spec)."""
+    dr.clear_plan_cache()
+    wl, tables = _full_reddit_case(n_sub=600, n_auth=90)
+    sess = Session(num_workers=4, backend="device")
+    for name, data in tables.items():
+        sess.write(name, data)                 # round-robin: scatter plans
+    res = sess.run(wl)                         # rebucket, then write: place
+    assert res.stats.device_repartitions == 2
+    keys = dr.plan_keys()
+    assert {k[0] for k in keys} == {"rebucket", "scatter", "place"}, keys
+    for key in keys:
+        if key[0] == "place":
+            assert len(key) == 5 and isinstance(key[-1], tuple), key
+        else:
+            assert len(key) == (6 if key[0] == "rebucket" else 7), key
+            assert all(isinstance(f, bool) for f in key[-2:]), key

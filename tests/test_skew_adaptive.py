@@ -212,8 +212,7 @@ def test_skew_levels_share_one_scatter_trace():
             pids_d, hist = dr.device_partition_ids(keys, m)
             counts = np.asarray(hist).astype(np.int64)
             cmap = CapacityMap.from_counts(counts)
-            dr.device_scatter_padded(data, pids_d, counts, capacity_map=cmap,
-                                     mode="fused")
+            dr.device_scatter_padded(data, pids_d, counts, capacity_map=cmap)
             traces.append(dr.plan_cache_stats()["traces"])
         assert traces[1] == traces[0] and traces[2] == traces[0], traces
     finally:

@@ -17,11 +17,12 @@ from repro.data.partition_store import PartitionStore
 from repro.data.transfer import fetch, fetch_tree, upload
 
 
-@pytest.fixture(params=["fused", "hostperm"])
-def mode(request, monkeypatch):
-    """Both executions of the ShufflePlans (fused runs the jnp oracle in
-    place of the kernels on the CPU)."""
-    monkeypatch.setattr(dr, "default_mode", lambda: request.param)
+@pytest.fixture(params=[False, True], ids=["oracle", "kernel"])
+def use_kernel(request, monkeypatch):
+    """The ShufflePlans with the jnp oracle of each kernel (the CPU
+    default) and with the Pallas kernels, in interpret mode on the CPU
+    (the chip's kernel path)."""
+    monkeypatch.setattr(dr, "default_use_kernel", lambda: request.param)
     return request.param
 
 
@@ -59,7 +60,7 @@ def _device_bytes(ds):
                if isinstance(v, jax.Array))
 
 
-def test_query_scan_and_shuffle_spans(mode):
+def test_query_scan_and_shuffle_spans(use_kernel):
     subs, auths = _data()
     store = PartitionStore(num_workers=8, backend="device")
     stored = {"submissions": store.write("submissions", subs),
@@ -138,7 +139,7 @@ def test_transfer_helpers_count_only_crossings():
     assert outer.args == {"d2h_bytes": 48}
 
 
-def test_write_counts_the_key_upload_once(mode):
+def test_write_counts_the_key_upload_once(use_kernel):
     subs, _ = _data()
     wl = author_integrator()
     part = enumerate_candidates(wl.graph, "submissions")[0]
@@ -149,22 +150,19 @@ def test_write_counts_the_key_upload_once(mode):
     (pids,) = _children(write, "store.pids")
     (disp,) = _children(write, "shuffle.dispatch")
     n = len(subs["author"])
-    if mode == "fused":           # int32 keys up, the histogram back
-        assert pids.args == {"h2d_bytes": 4 * n, "d2h_bytes": 4 * 8}
-    else:                         # host keys hash on the host
-        assert pids.args == {"h2d_bytes": 0, "d2h_bytes": 0}
-    assert disp.args["op"] == "scatter" and disp.args["h2d_bytes"] > 0
-    if mode == "fused":
-        # the packs (f32, s32 and the three uint8 columns; the int64 stays
-        # on the host), bucketed to B rows, and the int32 counts and offsets
-        B = dr.shape_bucket(n)
-        assert disp.args["h2d_bytes"] == B * (4 + 4 + 3) + 2 * 4 * 8
-        assert disp.args["d2h_bytes"] == 4 * B    # flat_dest, for "big"
+    # int32 keys up, the histogram back
+    assert pids.args == {"h2d_bytes": 4 * n, "d2h_bytes": 4 * 8}
+    assert disp.args["op"] == "scatter"
+    # the packs (f32, s32 and the three uint8 columns; the int64 stays on
+    # the host), bucketed to B rows, and the int32 counts and offsets
+    B = dr.shape_bucket(n)
+    assert disp.args["h2d_bytes"] == B * (4 + 4 + 3) + 2 * 4 * 8
+    assert disp.args["d2h_bytes"] == 4 * B        # flat_dest, for "big"
     assert "h2d_bytes" not in write.args
     assert pids.t1 <= disp.t0
 
 
-def test_device_repartition_spans(mode):
+def test_device_repartition_spans(use_kernel):
     subs, _ = _data()
     wl = author_integrator()
     part = enumerate_candidates(wl.graph, "submissions")[0]
@@ -179,9 +177,8 @@ def test_device_repartition_spans(mode):
     (disp,) = _children(rep, "shuffle.dispatch")
     assert flat.args["h2d_bytes"] == 4 * ds.num_rows
     # the keys are projected from device columns: nothing to upload; the
-    # histogram (fused) or the bucketed pids (hostperm) come back
-    assert pids.args == {"h2d_bytes": 0, "d2h_bytes": 4 * (
-        8 if mode == "fused" else dr.shape_bucket(ds.num_rows))}
+    # histogram comes back
+    assert pids.args == {"h2d_bytes": 0, "d2h_bytes": 4 * 8}
     assert flat.t1 <= pids.t0 <= pids.t1 <= disp.t0
     assert new.num_rows == ds.num_rows
 
